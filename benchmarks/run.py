@@ -1009,9 +1009,12 @@ def _bench_kernels(dest: Path, scale: str):
     The kernels' contract is *bit-identity with the XLA paths they replace*
     — a select kernel that reorders ties or a scatter kernel that drops a
     histogram count would silently skew every downstream coverage number.
-    So the gates are exact, CI-fatal, and run the kernel bodies through the
-    Pallas interpreter (``interpret=True``) so a CPU-only CI executes the
-    same code a TPU compiles:
+    So the gates are exact and CI-fatal.  The kernels run as
+    ``dispatch.resolve_backend(use_pallas=True)`` resolves them: compiled on
+    a TPU (``hist_select`` only — ``observe_scatter`` has no TPU lowering,
+    so the scatter gate has nothing to compare there), and through the
+    Pallas interpreter elsewhere, so a CPU-only CI executes the same kernel
+    bodies:
 
       1. per size: ``hist_select.kth_key_u`` == its jnp oracle, and
          ``select_top_k`` / ``top_k_mask`` / ``segment_top_k_mask`` with a
@@ -1024,27 +1027,32 @@ def _bench_kernels(dest: Path, scale: str):
          faults — while the epoch loop still costs exactly 2 dispatches
          and at most one trace of the fused step.
 
-    Wall-time rows compare the XLA select/scatter against the interpreted
-    kernels; they are parity-run timings, not TPU performance (the
-    interpreter is orders slower than a compiled kernel — compiled numbers
-    need TPU hardware).
+    Wall-time rows compare the XLA select/scatter against the kernels in
+    the resolved mode, and the report names the device they ran on; an
+    interpret-mode row is a parity-run timing, not TPU performance.
     """
     import json
+    import jax
     import jax.numpy as jnp
     from repro.core import runtime as rtmod
     from repro.core import selectk
     from repro.core.runtime import EpochRuntime, Tenancy
     from repro.faults import FaultModel
-    from repro.kernels.dispatch import PallasBackend
+    from repro.kernels.dispatch import resolve_backend
     from repro.kernels.hist_select import kth_key_u, kth_key_u_ref
     from repro.kernels.observe_scatter import observe_scatter
 
     smoke = scale == "smoke"
     rng = np.random.default_rng(29)
-    backend = PallasBackend(interpret=True, select_tile_n=1024,
-                            scatter_tile_m=512)
-    report = {"scale": scale, "interpret": True, "gates": {},
-              "select": [], "scatter": []}
+    backend = resolve_backend(True, n_blocks=131072, select_tile_n=1024,
+                              scatter_tile_m=512)
+    mode = "interpret" if backend.interpret else "compiled"
+    dev = jax.devices()[0]
+    report = {"scale": scale, "interpret": backend.interpret,
+              "kernels": backend.describe(),
+              "device": {"platform": dev.platform, "kind": dev.device_kind,
+                         "count": len(jax.devices())},
+              "gates": {}, "select": [], "scatter": []}
     ok = True
 
     # -- 1. hist_select parity + timing per size ------------------------
@@ -1059,7 +1067,7 @@ def _bench_kernels(dest: Path, scale: str):
         seg = jnp.zeros((n,), jnp.int32)
         t_ref = kth_key_u_ref(u, seg, (k,))
         t_pal = kth_key_u(u, seg, (k,), tile_n=backend.select_tile_n,
-                          use_pallas=True, interpret=True)
+                          use_pallas=True, interpret=backend.interpret)
         kth_ok = bool(jnp.array_equal(t_ref, t_pal))
 
         key = jnp.asarray(
@@ -1086,14 +1094,20 @@ def _bench_kernels(dest: Path, scale: str):
         point_ok = kth_ok and sel_ok and seg_ok
         report["select"].append({
             "n": n, "rows": B, "k": k, "bit_identical": point_ok,
-            "xla_us": xla_s * 1e6, "pallas_interpret_us": pal_s * 1e6})
+            "xla_us": xla_s * 1e6, "pallas_us": pal_s * 1e6,
+            "mode": mode})
         ok &= point_ok
         _row(f"kernels_hist_select_n{n}", pal_s * 1e6,
              f"bit_identical={point_ok} xla={xla_s * 1e6:.0f}us "
-             f"interpret={pal_s * 1e6:.0f}us (parity run, not TPU perf)")
+             f"{mode}={pal_s * 1e6:.0f}us on {dev.platform}")
 
     # -- 2. observe_scatter parity + timing per size --------------------
     scatter_sizes = [(4096, 997)] if smoke else [(4096, 997), (65536, 20000)]
+    if not backend.uses_scatter_kernel:
+        scatter_sizes = []
+        _row("kernels_observe_scatter", 0.0,
+             f"not run: scatter site resolved to {backend.scatter} on "
+             f"{dev.platform}")
     for M, n_blocks in scatter_sizes:
         ids = rng.integers(-3, n_blocks + 3, size=(M,)).astype(np.int32)
         keep = rng.random(M) < 0.7
@@ -1107,7 +1121,7 @@ def _bench_kernels(dest: Path, scale: str):
                                      use_pallas=False, **args)
             h1, p1 = observe_scatter(ids, cursor, keep=km,
                                      tile_m=backend.scatter_tile_m,
-                                     use_pallas=True, interpret=True, **args)
+                                     use_pallas=True, **args)
             point_ok &= bool(jnp.array_equal(h0, h1))
             point_ok &= bool(jnp.array_equal(p0, p1))
         t0 = _now()
@@ -1115,15 +1129,16 @@ def _bench_kernels(dest: Path, scale: str):
         xla_s = _elapsed(t0, hx, px)
         t0 = _now()
         hp, pp = observe_scatter(ids, cursor, tile_m=backend.scatter_tile_m,
-                                 use_pallas=True, interpret=True, **args)
+                                 use_pallas=True, **args)
         pal_s = _elapsed(t0, hp, pp)
         report["scatter"].append({
             "m": M, "n_blocks": n_blocks, "bit_identical": point_ok,
-            "xla_us": xla_s * 1e6, "pallas_interpret_us": pal_s * 1e6})
+            "xla_us": xla_s * 1e6, "pallas_us": pal_s * 1e6,
+            "mode": mode})
         ok &= point_ok
         _row(f"kernels_observe_scatter_m{M}", pal_s * 1e6,
              f"bit_identical={point_ok} xla={xla_s * 1e6:.0f}us "
-             f"interpret={pal_s * 1e6:.0f}us (parity run, not TPU perf)")
+             f"{mode}={pal_s * 1e6:.0f}us on {dev.platform}")
     report["gates"]["select_bit_identical"] = all(
         p["bit_identical"] for p in report["select"])
     report["gates"]["scatter_bit_identical"] = all(
@@ -1142,8 +1157,7 @@ def _bench_kernels(dest: Path, scale: str):
         rt = EpochRuntime(n, k, policies=policies,
                           pebs_period=max(shape[0] * shape[1] // (4 * k), 1),
                           nb_scan_rate=n // 4, fused=True, sync_every=2,
-                          use_pallas=use_pallas,
-                          pallas_interpret=use_pallas or None, **kw)
+                          use_pallas=use_pallas, **kw)
         with rtmod.counting() as c:
             t0 = _now()
             rt.run(iter(eps))
@@ -1171,7 +1185,7 @@ def _bench_kernels(dest: Path, scale: str):
         cfg_ok = identical and disp <= 2 and traces <= 1
         report[f"runtime_{label}"] = {
             "bit_identical": identical, "dispatches_per_epoch": disp,
-            "traces": traces, "wall_s": wall}
+            "traces": traces, "wall_s": wall, "kernels": on.kernels}
         runtime_gate &= cfg_ok
         _row(f"kernels_runtime_{label}", wall / n_epochs * 1e6,
              f"bit_identical={identical} dispatches={disp:.0f}/ep "
@@ -1315,8 +1329,9 @@ def main() -> None:
                          "BENCH_faults.json")
     ap.add_argument("--kernels", action="store_true",
                     help="epoch_runtime --json: bench the Pallas telemetry "
-                         "kernels (hist_select / observe_scatter, interpret "
-                         "mode), gate pallas-vs-XLA bit-identity per size + "
+                         "kernels as resolve_backend picks them (compiled "
+                         "on TPU, interpret mode elsewhere), gate "
+                         "pallas-vs-XLA bit-identity per size + "
                          "fused-runtime bit-identity at 2 dispatches/epoch, "
                          "write results/BENCH_kernels.json")
     ap.add_argument("--export", action="store_true",
@@ -1351,6 +1366,8 @@ def main() -> None:
     if args.obs and not args.json:
         ap.error("--obs gates run inside the --json bench; "
                  "add --json (or drop --obs)")
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache(Path(__file__).resolve().parent.parent)
     print("name,us_per_call,derived")
     for name, fn in ALL.items():
         if args.only and name != args.only:

@@ -835,7 +835,6 @@ class EpochRuntime:
         hardening: Optional[Hardening] = None,
         export=None,
         use_pallas: Optional[bool] = None,
-        pallas_interpret: Optional[bool] = None,
     ):
         unknown = set(policies) - set(ALL_POLICIES)
         if unknown:
@@ -854,20 +853,18 @@ class EpochRuntime:
             hardening = Hardening.make(**dict(hardening))
         if hardening is not None:
             hardening.validate()
-        # Pallas kernels are single-device VMEM programs; under a mesh the
-        # sharded XLA path stays authoritative.  use_pallas=None quietly
-        # resolves to off in that case; an explicit True is a config error.
-        if use_pallas and mesh is not None:
-            raise ValueError("use_pallas=True is incompatible with mesh "
-                             "sharding (the kernels carry whole histograms "
-                             "in one core's VMEM); drop mesh or use_pallas")
+        # The resolved backend records the implementation of each kernel
+        # site (``kernels``); an explicit use_pallas=True it cannot honour —
+        # past hist_select's size bound, or under a mesh, where the sharded
+        # XLA path stays authoritative — raises.
         if use_pallas and not fused:
             raise ValueError("the Pallas kernels run inside the fused epoch "
                              "step; the reference path stays the pure-XLA "
                              "bit-identity oracle — pass fused=True or drop "
                              "use_pallas")
-        self._pallas = (resolve_backend(use_pallas, pallas_interpret)
-                        if fused and mesh is None else None)
+        self._pallas = resolve_backend(
+            use_pallas if fused else False, n_blocks=int(n_blocks),
+            sharded=mesh is not None)
         self.sync_every = int(sync_every)
         if self.sync_every < 1:
             raise ValueError(f"sync_every must be >= 1, got {sync_every!r}")
@@ -1020,6 +1017,13 @@ class EpochRuntime:
         return cls(scenario.n_blocks, scenario.k_hot, **kw)
 
     # ------------------------------------------------------- state accessors
+    @property
+    def kernels(self) -> Dict[str, str]:
+        """Implementation of each kernel site (``select``, ``scatter``) this
+        runtime resolved at its size, e.g. ``{"select": "hist_select
+        (compiled)", "scatter": "xla"}``."""
+        return self._pallas.describe()
+
     @property
     def lanes(self) -> Dict[str, _Lane]:
         """Per-lane placement view (host copies in fused mode)."""

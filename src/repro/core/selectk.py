@@ -24,11 +24,14 @@ share one selection kernel.  Every function is shape-polymorphic over leading
 batch (lane) axes and safe under ``vmap``/``jit``/SPMD partitioning.
 
 All selection entry points take an optional ``backend`` (a
-``repro.kernels.dispatch.PallasBackend``): when set and ``k`` is static, the
-32-round threshold search is replaced by the ``kernels.hist_select`` Pallas
-radix-histogram kernel (4 grid passes instead of 32), bit-identical by the
-same largest-``t``-with-``count(u >= t) >= k`` definition.  ``None`` (the
-default) keeps the pure-XLA path.
+``repro.kernels.dispatch.PallasBackend``): when its ``select`` site is
+``"hist_select"`` and ``k`` is static, the 32-round threshold search is
+replaced by the ``kernels.hist_select`` Pallas radix-histogram kernel (4
+grid passes instead of 32), bit-identical by the same
+largest-``t``-with-``count(u >= t) >= k`` definition.  ``None`` (the
+default) keeps the pure-XLA path.  The size rule lives in
+``dispatch.resolve_backend``; a row past ``hist_select.MAX_N`` raises
+here instead of quietly taking the XLA search.
 """
 from __future__ import annotations
 
@@ -121,13 +124,16 @@ def _kth_largest(u: jax.Array, k) -> jax.Array:
     return jax.lax.fori_loop(0, 32, body, jnp.zeros(u.shape[:-1], jnp.uint32))
 
 
+def _uses_kernel(backend) -> bool:
+    return backend is not None and backend.uses_select_kernel
+
+
 def _kth_dispatch(u: jax.Array, k, backend) -> jax.Array:
-    """k-th-largest threshold: the hist_select radix kernel when a Pallas
-    backend is live and ``k`` is static (4 grid passes), the 32-round
+    """k-th-largest threshold: the hist_select radix kernel when the
+    backend selects it and ``k`` is static (4 grid passes), the 32-round
     bitwise search otherwise.  Identical thresholds either way: both
     compute the largest ``t`` with ``count(u >= t) >= k``."""
-    if (backend is None or not isinstance(k, int)
-            or u.shape[-1] > hist_select.MAX_N):
+    if not _uses_kernel(backend) or not isinstance(k, int):
         return _kth_largest(u, k)
     n = u.shape[-1]
     t = hist_select.kth_key_u(
@@ -229,7 +235,7 @@ def segment_top_k_mask(key: jax.Array, bounds, caps, *,
     prefix sums rebased at the static segment starts — bit-identical to the
     per-slice path, without its S separate selects.
     """
-    if backend is None:
+    if not _uses_kernel(backend):
         parts = [
             top_k_mask(jax.lax.slice_in_dim(key, int(a), int(b), axis=-1),
                        min(int(cap), int(b) - int(a)))

@@ -433,6 +433,8 @@ def _bundle_observe(bundle: TelemetryBundle, block_ids: jax.Array,
     f = bundle.faults
     flat = block_ids.reshape(-1)
     m = flat.shape[0]
+    if pallas is not None and not pallas.uses_scatter_kernel:
+        pallas = None
     if f is None:
         hist = pebs_hist = n_kept = touched = None
         if pallas is not None:
@@ -545,10 +547,11 @@ def observe_all(bundle: TelemetryBundle, batches: jax.Array,
     already free to flush the previous epochs' batched record sync
     (``EpochRuntime`` with ``sync_every=K``) while the scan runs.
 
-    ``pallas`` (a static ``repro.kernels.dispatch.PallasBackend``) swaps
-    the per-collector scatters inside the scan for ONE ``observe_scatter``
-    kernel pass per batch — one read of the id stream feeding all four
-    collector updates — still a single dispatch, bit-identical states.
+    ``pallas`` (a static ``repro.kernels.dispatch.PallasBackend``) whose
+    ``scatter`` site is ``"observe_scatter"`` swaps the per-collector
+    scatters inside the scan for ONE kernel pass per batch — one read of
+    the id stream feeding all four collector updates — still a single
+    dispatch, bit-identical states.
     """
     TRACE_COUNTS["observe_all"] += 1
     if bundle.faults is not None:

@@ -255,8 +255,9 @@ def run_fleet(
     ``EpochRuntime.for_scenario``) but keeps the runtime in hand so the
     per-tenant accounting (``fleet.accounting``) can be sliced from
     ``EpochRuntime.tenant_records``.  Returns ``{"trajectory", "summary",
-    "tenants"}`` — the tenants section holds one coverage/accuracy/time row
-    per tenant per lane per epoch plus headline summaries.
+    "tenants", "kernels"}`` — the tenants section holds one coverage/
+    accuracy/time row per tenant per lane per epoch plus headline
+    summaries; ``kernels`` is ``EpochRuntime.kernels``.
 
     ``sync_every=K`` batches the runtime's record syncs — the per-tenant
     ``(n_lanes, n_tenants)`` rows ride the same every-K transfer as the
@@ -307,6 +308,7 @@ def run_fleet(
         "summary": summary,
         "tenants": accounting.tenant_summary(rt, fleet, policies,
                                              export=exp),
+        "kernels": rt.kernels,
     }
     if solo:
         solos: Dict[str, dict] = {}

@@ -90,7 +90,7 @@ def embedding_bag_pallas(
         num_scalar_prefetch=1,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),                # storage in HBM
+            pl.BlockSpec(memory_space=pl.ANY),                # storage in HBM
             pl.BlockSpec((1, l), lambda i, idx: (i, 0)),         # weights row
             pl.BlockSpec((n_blocks, 1), lambda i, idx: (0, 0)),  # counts
         ],

@@ -107,7 +107,7 @@ def gather_count_pallas(
         num_scalar_prefetch=1,
         grid=(m // tile_m,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),       # storage stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),       # storage stays in HBM
             pl.BlockSpec((n_blocks, 1), lambda i, idx: (0, 0)),  # counts in VMEM
         ],
         out_specs=[

@@ -1,9 +1,11 @@
 """jit'd public wrapper for gather_count.
 
-Dispatches to the Pallas TPU kernel on TPU backends and to the pure-jnp
-reference elsewhere (CPU dry-runs / tests run the kernel in interpret mode
-explicitly).  The wrapper pads the index vector to the tile size so callers
-can pass arbitrary M.
+``use_pallas=True`` runs the Pallas kernel through the interpreter — the
+only mode it has: Mosaic refuses its per-index counter bump ("Cannot store
+scalars to VMEM"), so it does not compile for TPU and a request for the
+compiled kernel raises.  ``use_pallas=False`` (the default) runs the
+pure-jnp reference on every platform.  The wrapper pads the index vector to
+the tile size so callers can pass arbitrary M.
 """
 from __future__ import annotations
 
@@ -16,10 +18,6 @@ from .kernel import DEFAULT_TILE_M, gather_count_pallas
 from .ref import gather_count_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("block_rows", "tile_m", "use_pallas", "interpret"))
 def gather_count(
     storage: jax.Array,
@@ -28,14 +26,16 @@ def gather_count(
     *,
     block_rows: int,
     tile_m: int = DEFAULT_TILE_M,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
+    use_pallas: bool = False,
+    interpret: bool = True,
 ):
     """Tier-aware gather + HMU counter update.  Returns (rows, new_counts)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     if not use_pallas:
         return gather_count_ref(storage, indices, counts, block_rows=block_rows)
+    if not interpret:
+        raise ValueError("gather_count does not compile for TPU (Mosaic "
+                         "cannot store scalars to VMEM); it runs in interpret "
+                         "mode only")
 
     m = indices.shape[0]
     pad = (-m) % tile_m

@@ -1,11 +1,12 @@
 """jit'd public wrapper for hist_select.
 
 ``kth_key_u`` is the backend primitive ``selectk`` plugs in: per batch row
-and per static segment, the k-th largest uint32 key.  Dispatches to the
-Pallas radix-histogram kernel on TPU (or in ``interpret=True`` mode for CPU
-parity runs) and to the pure-jnp sort oracle otherwise.  The wrapper pads
-the key axis to the tile size with segment id -1, which matches no segment's
-one-hot row — padding never enters any histogram.
+and per static segment, the k-th largest uint32 key.  ``use_pallas=True``
+runs the Pallas radix-histogram kernel (compiled on TPU, or with
+``interpret=True`` for CPU parity runs); ``False`` runs the pure-jnp sort
+oracle.  The wrapper pads the key axis to the tile size with segment id -1,
+which matches no segment's one-hot row — padding never enters any
+histogram.
 """
 from __future__ import annotations
 
@@ -17,14 +18,10 @@ import jax.numpy as jnp
 from .kernel import DEFAULT_TILE_N, kth_key_u_pallas
 from .ref import kth_key_u_ref
 
-# f32 histogram accumulation (tile matmul + cumsum) is exact for integer
-# counts below 2**24; callers must fall back to the 32-round search past it.
+# f32 histogram accumulation (tile matmuls) is exact for integer counts
+# below 2**24; ``dispatch.resolve_backend`` picks the 32-round XLA search
+# for larger key rows, and the kernel refuses them.
 MAX_N = 1 << 23
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
 
 @partial(jax.jit, static_argnames=("ks", "tile_n", "use_pallas", "interpret"))
 def kth_key_u(
@@ -33,7 +30,7 @@ def kth_key_u(
     ks: tuple,                     # static per-segment selection widths
     *,
     tile_n: int = DEFAULT_TILE_N,
-    use_pallas: bool | None = None,
+    use_pallas: bool = True,
     interpret: bool = False,
 ) -> jax.Array:                    # (B, S) uint32 thresholds
     """Per-(row, segment) k-th largest key.  ``0 <= ks[s] <= |segment s|``."""
@@ -41,8 +38,6 @@ def kth_key_u(
     if n > MAX_N:
         raise ValueError(f"n={n} exceeds hist_select's exact-count bound "
                          f"MAX_N={MAX_N}; use the selectk XLA path")
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     if not use_pallas:
         return kth_key_u_ref(u, seg_ids, ks)
 

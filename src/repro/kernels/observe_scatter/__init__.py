@@ -6,9 +6,10 @@ of: the full access histogram (HMU saturating add, NB touched set,
 true-count add) and the PEBS-sampled histogram (the in-kernel
 ``(cursor + position) % period`` sampler, optionally masked by a fault
 model's per-event keep draw) — one read of the id stream feeding all four
-collectors, replacing their four per-batch scatters.
+collectors, replacing their four per-batch scatters.  It runs only in
+interpret mode (see ``ops``); every compiled path uses the XLA scatters.
 """
-from .ops import MAX_BLOCKS, observe_scatter
+from .ops import observe_scatter
 from .ref import observe_scatter_ref
 
-__all__ = ["observe_scatter", "observe_scatter_ref", "MAX_BLOCKS"]
+__all__ = ["observe_scatter", "observe_scatter_ref"]

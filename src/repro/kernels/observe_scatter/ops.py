@@ -1,7 +1,10 @@
 """jit'd public wrapper for observe_scatter.
 
-Dispatches to the Pallas TPU kernel on TPU backends (or in interpret mode
-for CPU parity runs) and to the pure-jnp reference elsewhere.  Pads the id
+``use_pallas=True`` runs the Pallas kernel through the interpreter — the
+only mode it has: Mosaic refuses its per-id VMEM read-modify-write
+("Cannot store scalars to VMEM"), so it does not compile for TPU and a
+request for the compiled kernel raises.  ``use_pallas=False`` runs the
+pure-jnp reference (the XLA scatter every platform uses).  Pads the id
 stream to the tile size with ``n_blocks`` — out of range for both paths
 (negative ids WRAP once, NumPy-style, so they cannot pad) — so callers
 pass arbitrary batch sizes.
@@ -16,15 +19,6 @@ import jax.numpy as jnp
 from .kernel import DEFAULT_TILE_M, observe_scatter_pallas
 from .ref import observe_scatter_ref
 
-# both histograms ride whole in VMEM across the grid; past ~1M blocks they
-# stop fitting alongside the working tiles — callers fall back to XLA
-MAX_BLOCKS = 1 << 20
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit,
          static_argnames=("n_blocks", "period", "tile_m", "use_pallas",
                           "interpret"))
@@ -36,15 +30,17 @@ def observe_scatter(
     period: int,
     keep: jax.Array | None = None,  # (M,) bool fault-model survival mask
     tile_m: int = DEFAULT_TILE_M,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
+    use_pallas: bool = False,
+    interpret: bool = True,
 ):
     """Fused epoch-batch telemetry scatter -> (hist, pebs_hist)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas or n_blocks > MAX_BLOCKS:
+    if not use_pallas:
         return observe_scatter_ref(ids, cursor, n_blocks=n_blocks,
                                    period=period, keep=keep)
+    if not interpret:
+        raise ValueError("observe_scatter does not compile for TPU (Mosaic "
+                         "cannot store scalars to VMEM); it runs in "
+                         "interpret mode only")
     m = ids.shape[0]
     tile = min(tile_m, -(-m // 128) * 128)
     pad = (-m) % tile

@@ -35,7 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import (ARCH_IDS, get_config, get_optimizer_name,
                            get_sharding_overrides)
 from repro.launch import sharding as sh
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, applicable, input_specs
 from repro.models.model import abstract_params, ModelConfig
 from repro.optim import get_optimizer, cosine_schedule
@@ -118,8 +118,6 @@ def build_step(cfg: ModelConfig, shape, mesh, overrides):
         bspecs = sh.batch_specs(mesh, cfg, batch_abs)
         jitted = jax.jit(
             step_fn,
-            # explicit NamedShardings: older jax (< 0.6) rejects raw
-            # PartitionSpecs in in_shardings even under an ambient mesh
             in_shardings=sh.named(mesh, (pspecs, opt_specs, bspecs)),
             out_shardings=(*sh.named(mesh, (pspecs, opt_specs)), None),
             donate_argnums=(0, 1),
@@ -184,7 +182,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
     overrides = get_sharding_overrides(arch)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build_step(cfg, shape, mesh, overrides)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0

@@ -2,10 +2,23 @@
 
 A function (not a module-level constant) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS before first init.
+
+Every mesh is built with ``AxisType.Auto`` axes: the sharded code (GSPMD
+scatters, ``with_sharding_constraint`` hints, ``shard_map`` islands) is
+written for compiler-propagated shardings, not the explicit-sharding type
+system ``jax.make_mesh`` defaults to.  Install a mesh as the ambient one
+with ``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """Auto-sharded mesh of the given shape (e.g. (2,4) on 8 devices)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,14 +28,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     pods once per step over DCN); "data" is FSDP + batch; "model" is tensor/
     expert parallel (stays inside a pod's ICI torus).
     """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests (e.g. (2,4) on 8 host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_telemetry_mesh(n_devices: int | None = None, axis: str = "blocks"):
@@ -31,12 +39,4 @@ def make_telemetry_mesh(n_devices: int | None = None, axis: str = "blocks"):
     (5.24 M page) epoch runs keep the decision loop next to the counters.
     Defaults to all visible devices."""
     n = len(jax.devices()) if n_devices is None else int(n_devices)
-    return jax.make_mesh((n,), (axis,))
-
-
-def use_mesh(mesh):
-    """Ambient-mesh context, portable across jax versions: ``jax.set_mesh``
-    where it exists (>= 0.6), else the Mesh object itself (a context manager
-    with the same ambient-mesh effect on older releases)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    return make_mesh((n,), (axis,))

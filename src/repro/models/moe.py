@@ -52,17 +52,6 @@ def expert_access_batch(counts) -> np.ndarray:
     return np.repeat(np.arange(c.shape[0], dtype=np.int32), c)
 
 
-def _ambient_mesh():
-    """The mesh installed by the caller's ``use_mesh``/``set_mesh`` context,
-    portable across jax versions: ``get_abstract_mesh`` on >= 0.6; the
-    thread-resources physical mesh (what ``with mesh:`` sets) on 0.4.x."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    from jax._src import mesh as _mesh_internal
-    return _mesh_internal.thread_resources.env.physical_mesh
-
-
 class MoEParams(NamedTuple):
     router: jax.Array          # (D, E)
     w_gate: jax.Array          # (E, D, Fe)
@@ -180,7 +169,6 @@ def _moe_shard_map(x, p: MoEParams, tope, topw, top_k, capacity_factor,
     C = ceil(t_l*k*cf/E) rounded up to a multiple of gm so the all-to-all
     tiles evenly.  Wire bytes per device per direction = E*C*D — the routed
     token bytes, nothing else."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -193,7 +181,6 @@ def _moe_shard_map(x, p: MoEParams, tope, topw, top_k, capacity_factor,
     capacity = -(-capacity // gm) * gm            # multiple of gm
 
     bax = batch_axes
-    mesh = _ambient_mesh()
     xspec = P(bax, "model", None)
     kspec = P(bax, "model", None)
 
@@ -213,13 +200,14 @@ def _moe_shard_map(x, p: MoEParams, tope, topw, top_k, capacity_factor,
                              capacity, d, dtype)
         return out.reshape(bl, sl, d)
 
-    fn = shard_map(
-        interior, mesh=mesh,
+    # mesh=None: the ambient mesh installed by the caller's jax.set_mesh
+    fn = jax.shard_map(
+        interior,
         in_specs=(xspec, kspec, kspec,
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=xspec,
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(x, tope, topw, p.w_gate, p.w_up, p.w_down)
     out = _constrain(out, (bax, None, None))

@@ -174,7 +174,9 @@ def run_scenario(
     trajectories are bit-identical export-on vs export-off and the epoch
     dispatch count is unchanged.
 
-    Returns ``{"trajectory": per-epoch dict, "summary": headline numbers}``.
+    Returns ``{"trajectory": per-epoch dict, "summary": headline numbers,
+    "kernels": the implementation of each kernel site}`` (``kernels`` is
+    ``EpochRuntime.kernels``: which select and scatter code ran).
     """
     if hints is True:
         hints = build_hints(scenario, depth=lookahead_depth)
@@ -193,4 +195,5 @@ def run_scenario(
         "trajectory": json.loads(traj.to_json(scenario=scenario.name,
                                               shift_at=scenario.shift_at)),
         "summary": summary,
+        "kernels": rt.kernels,
     }

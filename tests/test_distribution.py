@@ -35,7 +35,7 @@ def test_sharded_train_step_matches_single_device():
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_smoke_config
         from repro.launch import sharding as sh
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.model import init_params
         from repro.optim import get_optimizer, cosine_schedule
         from repro.train.steps import make_train_step
@@ -58,7 +58,7 @@ def test_sharded_train_step_matches_single_device():
         mesh = make_mesh((2, 4), ("data", "model"))
         cfg2 = dataclasses.replace(cfg, act_batch_axes=("data",))
         step2 = make_train_step(cfg2, opt, cosine_schedule(1e-3, 10, 100))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             pspecs = sh.model_pspecs(mesh, cfg2)
             ospecs = sh.opt_pspecs(pspecs, state)
             bspecs = sh.batch_specs(mesh, cfg2, batch)
@@ -86,7 +86,7 @@ def test_dryrun_cell_compiles_on_small_mesh():
     r = run_py("""
         import jax, dataclasses
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch import sharding as sh, hloanalysis
         from repro.launch.shapes import ShapeSpec
         from repro.launch.dryrun import build_step
@@ -95,7 +95,7 @@ def test_dryrun_cell_compiles_on_small_mesh():
             cfg = get_smoke_config(arch)
             mesh = make_mesh((2, 4), ("data", "model"))
             shape = ShapeSpec("t", 64, 8, "train")
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 jitted, args = build_step(cfg, shape, mesh, {})
                 compiled = jitted.lower(*args).compile()
                 res = hloanalysis.analyze(compiled.as_text())
@@ -110,14 +110,14 @@ def test_serve_decode_compiles_sharded():
     r = run_py("""
         import jax
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.shapes import ShapeSpec
         from repro.launch.dryrun import build_step
 
         cfg = get_smoke_config("internlm2-1.8b")
         mesh = make_mesh((2, 4), ("data", "model"))
         shape = ShapeSpec("d", 128, 8, "decode")
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted, args = build_step(cfg, shape, mesh, {})
             compiled = jitted.lower(*args).compile()
         print("OK")
@@ -171,7 +171,7 @@ def test_shard_map_moe_matches_reference():
     r = run_py("""
         import numpy as np, jax, jax.numpy as jnp, dataclasses
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch import sharding as sh
         from repro.models.model import init_params, forward
 
@@ -193,7 +193,7 @@ def test_shard_map_moe_matches_reference():
         cfg_ref = dataclasses.replace(
             base, act_batch_axes=("data",),
             moe=dataclasses.replace(base.moe, capacity_factor=8.0))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             pspecs = sh.model_pspecs(mesh, cfg_ep)
             bspec = sh.batch_specs(mesh, cfg_ep, {"tokens": toks})["tokens"]
             shardings = sh.named(mesh, (pspecs, bspec))

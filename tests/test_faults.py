@@ -212,7 +212,8 @@ def test_neutral_model_bit_identical_sharded():
         import dataclasses, json
         from repro.dlrm import datagen
         from repro.faults import FaultModel
-        from repro.launch.mesh import make_telemetry_mesh, use_mesh
+        import jax
+        from repro.launch.mesh import make_telemetry_mesh
         from repro.scenarios import DLRMScenario, run_scenario
 
         spec = dataclasses.replace(datagen.SMALL, lookups_per_batch=8_000)
@@ -220,7 +221,7 @@ def test_neutral_model_bit_identical_sharded():
                           shift_at=2)
         ref = run_scenario(sc, hints=True)
         mesh = make_telemetry_mesh(8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             shd = run_scenario(
                 DLRMScenario(spec=spec, n_epochs=4, batches_per_epoch=2,
                              shift_at=2),

@@ -369,7 +369,8 @@ def test_sharded_fleet_parity():
         import numpy as np
         from repro.dlrm import datagen
         from repro.fleet import FleetScenario, TenantSpec, run_fleet
-        from repro.launch.mesh import make_telemetry_mesh, use_mesh
+        import jax
+        from repro.launch.mesh import make_telemetry_mesh
         from repro.scenarios import DLRMScenario, MmapBenchScenario
 
         spec = dataclasses.replace(datagen.SMALL, lookups_per_batch=8_000)
@@ -385,7 +386,7 @@ def test_sharded_fleet_parity():
         kw = dict(k_hot=280, capacity="weighted")
         ref = run_fleet(FleetScenario(tenants(), **kw), hints=True)
         mesh = make_telemetry_mesh(8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             shd = run_fleet(FleetScenario(tenants(), **kw), hints=True,
                             mesh=mesh)
         assert json.dumps(ref["trajectory"], sort_keys=True) == \\

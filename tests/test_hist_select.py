@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import selectk
-from repro.kernels.dispatch import PallasBackend
+from repro.kernels.dispatch import XLA, PallasBackend
 from repro.kernels.hist_select import MAX_N, kth_key_u, kth_key_u_ref
 
 BACKEND = PallasBackend(interpret=True, select_tile_n=512)
@@ -42,15 +42,23 @@ def test_kth_key_matches_ref_and_bitwise_search(n):
                                       err_msg=f"k={k}")
 
 
-def test_kth_key_rejects_oversized_input():
+@pytest.mark.parametrize("entry", ["kernel", "selectk_kernel", "selectk_xla"])
+def test_kth_key_rejects_oversized_input(entry):
+    """Past MAX_N the f32 counts are no longer exact: the kernel and a
+    backend that selects it raise, and only a backend that resolved the
+    select site to XLA takes the 32-round search."""
     n = MAX_N + 1
     u = jnp.zeros((1, n), jnp.uint32)
     seg = jnp.zeros((n,), jnp.int32)
+    if entry == "selectk_xla":
+        t = selectk._kth_dispatch(u, 1, XLA)
+        np.testing.assert_array_equal(np.asarray(t), [0])
+        return
     with pytest.raises(ValueError, match="MAX_N"):
-        kth_key_u(u, seg, (1,), use_pallas=True, interpret=True)
-    # selectk quietly takes the 32-round XLA search past the bound instead
-    t = selectk._kth_dispatch(u, 1, BACKEND)
-    np.testing.assert_array_equal(np.asarray(t), [0])
+        if entry == "kernel":
+            kth_key_u(u, seg, (1,), use_pallas=True, interpret=True)
+        else:
+            selectk._kth_dispatch(u, 1, BACKEND)
 
 
 # -------------------------------------------- selection entry-point parity

@@ -69,6 +69,25 @@ def test_embedding_bag_ragged_bag_grid():
     np.testing.assert_array_equal(np.asarray(c_p), np.asarray(c_r))
 
 
+@pytest.mark.parametrize("kernel", ["gather_count", "embedding_bag"])
+def test_compiled_request_raises_for_kernels_without_tpu_lowering(kernel):
+    """Neither kernel compiles for TPU: the default is the reference on
+    every platform, and asking for the compiled kernel is an error."""
+    storage = jnp.ones((64, 128), jnp.float32)
+    counts = jnp.zeros((8,), jnp.int32)
+    if kernel == "gather_count":
+        run = lambda **kw: gather_count(storage, jnp.asarray([0, 9], jnp.int32),
+                                        counts, block_rows=8, **kw)
+    else:
+        run = lambda **kw: embedding_bag(
+            storage, jnp.asarray([[0, 9]], jnp.int32), counts, block_rows=8,
+            **kw)
+    _, c = run()
+    np.testing.assert_array_equal(np.asarray(c), [1, 1, 0, 0, 0, 0, 0, 0])
+    with pytest.raises(ValueError, match="does not compile for TPU"):
+        run(use_pallas=True, interpret=False)
+
+
 def test_gather_count_accumulates_over_calls():
     storage = jnp.zeros((64, 128), jnp.float32)
     counts = jnp.zeros((8,), jnp.int32)

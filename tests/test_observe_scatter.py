@@ -15,8 +15,7 @@ import pytest
 from repro.core import telemetry as tel
 from repro.faults.model import FaultModel
 from repro.kernels.dispatch import PallasBackend
-from repro.kernels.observe_scatter import (MAX_BLOCKS, observe_scatter,
-                                           observe_scatter_ref)
+from repro.kernels.observe_scatter import observe_scatter, observe_scatter_ref
 
 BACKEND = PallasBackend(interpret=True, scatter_tile_m=256)
 
@@ -72,12 +71,24 @@ def test_observe_scatter_ref_matches_telemetry_scatters():
         np.bincount(np.asarray(ids)[hit], minlength=n_blocks))
 
 
-def test_observe_scatter_falls_back_past_max_blocks():
-    ids = jnp.zeros((8,), jnp.int32)
-    h, p = observe_scatter(ids, jnp.asarray(0, jnp.int32),
-                           n_blocks=MAX_BLOCKS + 1, period=3,
-                           use_pallas=True, interpret=True)
-    assert h.shape == (MAX_BLOCKS + 1,) and int(h[0]) == 8
+@pytest.mark.parametrize("interpret", [False, True])
+def test_observe_scatter_explicit_at_any_size(interpret):
+    """No quiet size switch: the kernel runs (interpreted) past the old 1M
+    bound, and the compiled kernel, which Mosaic refuses, is an error."""
+    n_blocks = (1 << 20) + 1
+    ids = jnp.asarray([0, 3, n_blocks - 1, 0, -1], jnp.int32)
+    cur = jnp.asarray(0, jnp.int32)
+    run = lambda: observe_scatter(ids, cur, n_blocks=n_blocks, period=3,
+                                  tile_m=128, use_pallas=True,
+                                  interpret=interpret)
+    if not interpret:
+        with pytest.raises(ValueError, match="does not compile for TPU"):
+            run()
+        return
+    h, p = run()
+    h_ref, p_ref = observe_scatter_ref(ids, cur, n_blocks=n_blocks, period=3)
+    np.testing.assert_array_equal(np.asarray(h), np.asarray(h_ref))
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(p_ref))
 
 
 # ------------------------------------------------- fused observe_all parity

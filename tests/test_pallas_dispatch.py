@@ -10,19 +10,52 @@ import pytest
 
 from repro.core import runtime as rt
 from repro.faults.model import FaultModel
+from repro.kernels import dispatch
 from repro.kernels.dispatch import PallasBackend, resolve_backend
 
 
-def test_resolve_backend_policy_off_tpu():
-    # this suite runs on CPU: default resolves to the XLA path, an explicit
-    # opt-in resolves to the interpreter unless interpret=False is forced
+_MAX_N = dispatch.MAX_N
+_BOTH_INTERPRET = ("interpret", "hist_select", "observe_scatter")
+_TPU_DEFAULT = ("compiled", "hist_select", "xla")
+_XLA = ("compiled", "xla", "xla")
+
+
+@pytest.mark.parametrize("platform,kw,want", [
+    # off TPU: XLA unless asked; asked -> both kernel bodies, interpreted
+    ("cpu", dict(), _XLA),
+    ("cpu", dict(use_pallas=False), _XLA),
+    ("cpu", dict(use_pallas=True), _BOTH_INTERPRET),
+    ("cpu", dict(use_pallas=True, n_blocks=_MAX_N + 1), "MAX_N"),
+    ("cpu", dict(use_pallas=True, sharded=True), "sharded"),
+    ("cpu", dict(n_blocks=5_000_000), _XLA),
+    # on TPU: compiled hist_select where it is exact and unsharded; the
+    # scatter kernel has no TPU lowering, so the scatter site is XLA
+    ("tpu", dict(), _TPU_DEFAULT),
+    ("tpu", dict(n_blocks=5_000_000), _TPU_DEFAULT),
+    ("tpu", dict(n_blocks=_MAX_N + 1), _XLA),
+    ("tpu", dict(sharded=True), _XLA),
+    ("tpu", dict(use_pallas=False), _XLA),
+    ("tpu", dict(use_pallas=True), _TPU_DEFAULT),
+    ("tpu", dict(use_pallas=True, n_blocks=_MAX_N + 1), "MAX_N"),
+    ("tpu", dict(use_pallas=True, sharded=True), "sharded"),
+    ("tpu", dict(n_blocks=_MAX_N), _TPU_DEFAULT),
+])
+def test_resolve_backend_policy_off_tpu(monkeypatch, platform, kw, want):
+    """Every decision is visible in the resolved backend, and an explicit
+    request that cannot be honoured raises (the platform is steered here,
+    in the test; the suite itself runs on CPU)."""
     assert jax.default_backend() != "tpu"
-    assert resolve_backend() is None
-    assert resolve_backend(False) is None
-    b = resolve_backend(True)
-    assert isinstance(b, PallasBackend) and b.interpret
-    assert resolve_backend(True, False) == PallasBackend(interpret=False)
-    assert resolve_backend(True, select_tile_n=256).select_tile_n == 256
+    monkeypatch.setattr(dispatch, "_platform", lambda: platform)
+    kw = {"n_blocks": 5_000, **kw}
+    if isinstance(want, str):
+        with pytest.raises(ValueError, match=want):
+            resolve_backend(**kw)
+        return
+    b = resolve_backend(**kw)
+    assert isinstance(b, PallasBackend)
+    assert ("interpret" if b.interpret else "compiled",
+            b.select, b.scatter) == want
+    assert resolve_backend(**kw, select_tile_n=256).select_tile_n == 256
 
 
 def test_runtime_rejects_pallas_with_mesh_or_reference_path():
@@ -30,8 +63,9 @@ def test_runtime_rejects_pallas_with_mesh_or_reference_path():
         rt.EpochRuntime(64, 8, use_pallas=True, mesh=object())
     with pytest.raises(ValueError, match="fused"):
         rt.EpochRuntime(64, 8, use_pallas=True, fused=False)
-    # quiet default: no kernels off-TPU, no error
-    assert rt.EpochRuntime(64, 8)._pallas is None
+    # default off TPU: no kernels, no error, and the decision is recorded
+    assert rt.EpochRuntime(64, 8).kernels == {"select": "xla",
+                                              "scatter": "xla"}
 
 
 def _run(n, k, eps, use_pallas, **kw):
@@ -63,7 +97,8 @@ def test_fused_runtime_pallas_bit_identical_two_dispatches(variant):
                                          seed=11, n_blocks=n)
     off, _, _ = _run(n, k, eps, use_pallas=False, **kw)
     on, disp, traces = _run(n, k, eps, use_pallas=True, **kw)
-    assert on._pallas is not None and on._pallas.interpret
+    assert on.kernels == {"select": "hist_select (interpret)",
+                          "scatter": "observe_scatter (interpret)"}
     assert disp == 2 and traces <= 1
     for lane in off.records:
         assert [a.to_dict() for a in off.records[lane]] \

@@ -268,7 +268,8 @@ def test_sharded_sync_every_parity():
     r = run_py("""
         import dataclasses, json
         from repro.dlrm import datagen
-        from repro.launch.mesh import make_telemetry_mesh, use_mesh
+        import jax
+        from repro.launch.mesh import make_telemetry_mesh
         from repro.scenarios.dlrm import run_online
 
         spec = dataclasses.replace(datagen.SMALL, lookups_per_batch=8_000)
@@ -276,7 +277,7 @@ def test_sharded_sync_every_parity():
                   seed=0, hints=True)
         ref = run_online(**kw)
         mesh = make_telemetry_mesh(8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             shd = run_online(mesh=mesh, sync_every=3, **kw)
         assert json.dumps(ref["trajectory"], sort_keys=True) == \\
             json.dumps(shd["trajectory"], sort_keys=True)

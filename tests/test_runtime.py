@@ -251,7 +251,8 @@ def test_sharded_observe_all_and_epoch_step_parity():
     r = run_py("""
         import dataclasses, json
         from repro.dlrm import datagen, tracesim
-        from repro.launch.mesh import make_telemetry_mesh, use_mesh
+        import jax
+        from repro.launch.mesh import make_telemetry_mesh
 
         spec = dataclasses.replace(datagen.SMALL, lookups_per_batch=8_000)
         # hints=True also proves the sharded per-epoch hint refresh
@@ -260,7 +261,7 @@ def test_sharded_observe_all_and_epoch_step_parity():
                   seed=0, hints=True)
         ref = tracesim.run_online(**kw)
         mesh = make_telemetry_mesh(8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             shd = tracesim.run_online(mesh=mesh, **kw)
         assert json.dumps(ref["trajectory"], sort_keys=True) == \\
             json.dumps(shd["trajectory"], sort_keys=True)
@@ -276,13 +277,14 @@ def test_paper_scale_sharded_online_run():
     r = run_py("""
         import dataclasses
         from repro.dlrm import datagen, tracesim
-        from repro.launch.mesh import make_telemetry_mesh, use_mesh
+        import jax
+        from repro.launch.mesh import make_telemetry_mesh
 
         spec = datagen.DLRMTraceSpec(n_params=5_368_709_120,
                                      lookups_per_batch=400_000)
         assert spec.n_pages == 5_242_880
         mesh = make_telemetry_mesh(8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = tracesim.run_online(
                 spec=spec, mesh=mesh, n_epochs=3, batches_per_epoch=2,
                 shift_at=2, k_hot=spec.n_pages // 64, seed=0)
