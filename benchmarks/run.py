@@ -803,8 +803,8 @@ def _bench_obs(dest: Path, scale: str):
       4. pipelining is *visible*: with sync_every=K>1 some record_sync
          span must begin after the host has already dispatched the next
          epoch's observe_all (guaranteed by _step_fused's code order) —
-         the same proof rendered into the chrome://tracing artifact
-         (trace_obs.json) with a synthesized device track;
+         the same spans written out as the chrome://tracing artifact
+         (trace_obs.json);
       5. everything exported — epoch/tenant records, runtime spans, the
          registry dump — validates against the frozen schema with zero
          drops on the healthy sink;
@@ -911,18 +911,16 @@ def _bench_obs(dest: Path, scale: str):
     report["gates"]["exact_span_accounting"] = span_ok
     ok &= span_ok
 
-    # gate 4: pipelining visible + chrome trace artifact with device track
+    # gate 4: pipelining visible, written out as a chrome trace artifact
     visible = chrometrace.pipelining_visible(tracer.spans)
     trace_path = dest / ("trace_obs.json" if scale == "full"
                          else "trace_obs.smoke.json")
-    doc = chrometrace.write_chrome_trace(
+    chrometrace.write_chrome_trace(
         trace_path, tracer.spans,
         metadata={"bench": "obs", "scale": scale,
                   "sync_every": sync_every, "n_epochs": n_epochs})
-    has_device_track = any(e["tid"] == "device" for e in doc["traceEvents"])
     report["gates"]["pipelining_visible"] = visible
-    report["gates"]["device_track_in_trace"] = has_device_track
-    ok &= visible and has_device_track
+    ok &= visible
 
     # gate 5: everything exported validates, zero drops on the healthy sink
     recs = sink.snapshot()

@@ -5,8 +5,8 @@ the host keeps dispatching new epochs while a previous window's records
 are still being pulled off the device. `repro.obs` makes that claim
 visible instead of argued: span-trace a run, write a Chrome trace, and
 open it in chrome://tracing or https://ui.perfetto.dev to watch the
-`record_sync` span overlap the next epoch's `observe_all` on the
-synthesized device track. This walkthrough:
+`record_sync` span start after the next epoch's `observe_all` dispatch.
+This walkthrough:
 
 * runs the same workload obs-off and obs-on (tracing + metrics registry
   + runtime_span/runtime_metric export) and checks nothing changed —
@@ -95,10 +95,10 @@ def main():
         trace_path, tracer.spans,
         metadata={"example": "runtime_timeline", "sync_every": SYNC_EVERY})
     visible = chrometrace.pipelining_visible(tracer.spans)
-    device_spans = [e for e in doc["traceEvents"] if e["tid"] == "device"]
+    syncs = [e["args"] for e in doc["traceEvents"]
+             if e["name"] == "record_sync"]
     print(f"\nchrome trace -> {trace_path}")
-    print(f"  {len(doc['traceEvents'])} events, device windows: "
-          f"{[e['name'] for e in device_spans]}")
+    print(f"  {len(doc['traceEvents'])} events, record syncs: {syncs}")
     print(f"  pipelining visible (sync_every={SYNC_EVERY}): {visible}")
     assert visible, "sync_every>1 must make record_sync overlap dispatch"
     print("  open in chrome://tracing or https://ui.perfetto.dev")

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from . import policy, selectk
+from ..obs.trace import named_scope
 
 __all__ = ["Placement", "apply_plan", "demote_idle", "plan_promotion"]
 
@@ -153,7 +154,8 @@ def apply_plan(p: Placement, want: jax.Array, est: jax.Array,
     n_free = cfree[..., -1:]
     new_rank = jnp.cumsum(new.astype(jnp.int32), axis=-1) - 1
     assign = new & (new_rank < n_free)
-    free_slot = selectk.compact(cfree, k)           # (..., k), fill -> k
+    with named_scope("placement.free_slots"):
+        free_slot = selectk.compact(cfree, k)       # (..., k), fill -> k
     slot_for = jnp.take_along_axis(
         free_slot, jnp.clip(new_rank, 0, k - 1), axis=-1)
     s2b = _scatter_ids(s2b, slot_for, assign, want)

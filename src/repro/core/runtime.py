@@ -1262,7 +1262,12 @@ class EpochRuntime:
         if batches.ndim != 2:
             raise ValueError(f"epoch batches must be 2-D, got {batches.shape}")
         if self.hints is not None:
-            self.set_hint_ranks(*self.hints.epoch_ranks(batches, lookahead))
+            _tr = obs_trace.get_tracer()
+            cm = (_tr.span("hints", epoch=self.epoch)
+                  if _tr.enabled else obs_trace.NOOP_SPAN)
+            with cm:
+                ranks = self.hints.epoch_ranks(batches, lookahead)
+            self.set_hint_ranks(*ranks)
         if self.fused:
             return self._step_fused(batches)
         return self._step_reference(batches)
@@ -1315,12 +1320,15 @@ class EpochRuntime:
         # dispatch calls — the --obs bench gates bit-identical records and
         # equal DISPATCH_COUNTS either way.
         _tr = obs_trace.get_tracer()
+        cm = (_tr.span("id_upload", epoch=self.epoch, bytes=batches.nbytes)
+              if _tr.enabled else obs_trace.NOOP_SPAN)
+        with cm:
+            ids = jax.device_put(batches)
         DISPATCH_COUNTS["observe_all"] += 1
         cm = (_tr.span("observe_all", epoch=self.epoch)
               if _tr.enabled else obs_trace.NOOP_SPAN)
         with cm:
-            bundle = tel.observe_all(state.bundle, jnp.asarray(batches),
-                                     pallas=self._pallas)
+            bundle = tel.observe_all(state.bundle, ids, pallas=self._pallas)
         state = dataclasses.replace(state, bundle=bundle)
         # Pipelining: this epoch's observe_all is already dispatched when a
         # full record buffer forces the previous K epochs' batched sync, so
@@ -1363,7 +1371,23 @@ class EpochRuntime:
         cm = (_tr.span("record_sync", epoch_base=base, n_epochs=n_buf)
               if _tr.enabled else obs_trace.NOOP_SPAN)
         with cm:
-            host = jax.device_get(self._state.out_buf)
+            # waiting for the device, then the transfer that follows
+            out_buf = self._state.out_buf
+            cm = (_tr.span("record_wait", epoch_base=base)
+                  if _tr.enabled else obs_trace.NOOP_SPAN)
+            with cm:
+                jax.block_until_ready(out_buf)
+            cm = (_tr.span("record_pull", epoch_base=base)
+                  if _tr.enabled else obs_trace.NOOP_SPAN)
+            with cm:
+                host = jax.device_get(out_buf)
+        cm = (_tr.span("record_assembly", epoch_base=base, n_epochs=n_buf)
+              if _tr.enabled else obs_trace.NOOP_SPAN)
+        with cm:
+            return self._assemble_records(host, base, n_buf)
+
+    def _assemble_records(self, host: dict, base: int, n_buf: int
+                          ) -> Dict[str, List[EpochRecord]]:
         tenant = host.get("tenant")
         qual = host.get("quality")
         flushed: Dict[str, List[EpochRecord]] = {

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import hist_select
+from ..obs.trace import named_scope
 
 __all__ = [
     "sortable_key", "select_top_k", "top_k_mask", "stable_rank_sparse",
@@ -147,13 +148,15 @@ def _selection_mask(u: jax.Array, k, backend=None):
     """Boolean mask of the k largest (ties resolved lowest-index-first) and
     its inclusive prefix count.  ``k``: static int or per-batch array."""
     k_b = k[..., None] if isinstance(k, jax.Array) else k
-    t = _kth_dispatch(u, k, backend)[..., None]
-    gt = u > t
-    eq = u == t
-    n_gt = jnp.sum(gt.astype(jnp.int32), axis=-1, keepdims=True)
-    eq_rank = prefix_sum(eq) - 1
-    sel = gt | (eq & (eq_rank < (k_b - n_gt)))
-    return sel, prefix_sum(sel)
+    with named_scope("selectk.threshold"):
+        t = _kth_dispatch(u, k, backend)[..., None]
+    with named_scope("selectk.mask"):
+        gt = u > t
+        eq = u == t
+        n_gt = jnp.sum(gt.astype(jnp.int32), axis=-1, keepdims=True)
+        eq_rank = prefix_sum(eq) - 1
+        sel = gt | (eq & (eq_rank < (k_b - n_gt)))
+        return sel, prefix_sum(sel)
 
 
 def top_k_mask(key: jax.Array, k: int, *, backend=None) -> jax.Array:
@@ -195,8 +198,8 @@ def select_top_k(key: jax.Array, k: int, return_mask: bool = False,
     k = min(k, n)
     u = _to_u(key)
     sel, csel = _selection_mask(u, k, backend)
-    ids = compact(csel, k)                        # ascending index order
-    u_sel = jnp.take_along_axis(u, ids, axis=-1)
+    with named_scope("selectk.compact"):
+        ids = compact(csel, k)                    # ascending index order
 
     def order(us, i):
         # ascending ~u == descending u; stable keeps ascending-index ties
@@ -204,8 +207,10 @@ def select_top_k(key: jax.Array, k: int, return_mask: bool = False,
 
     for _ in range(key.ndim - 1):
         order = jax.vmap(order)
-    ids_sorted = order(u_sel, ids)
-    vals = jnp.take_along_axis(key, ids_sorted, axis=-1)
+    with named_scope("selectk.order"):
+        u_sel = jnp.take_along_axis(u, ids, axis=-1)
+        ids_sorted = order(u_sel, ids)
+        vals = jnp.take_along_axis(key, ids_sorted, axis=-1)
     if return_mask:
         return vals, ids_sorted, sel
     return vals, ids_sorted
@@ -249,9 +254,10 @@ def segment_top_k_mask(key: jax.Array, bounds, caps, *,
     ks = tuple(min(int(c), int(l)) for c, l in zip(caps, lens))
     seg = np.repeat(np.arange(len(ks), dtype=np.int32), lens)
     u = _to_u(key).reshape((-1, n))
-    t = hist_select.kth_key_u(
-        u, jnp.asarray(seg), ks, tile_n=backend.select_tile_n,
-        use_pallas=True, interpret=backend.interpret)       # (B, S) uint32
+    with named_scope("selectk.threshold"):
+        t = hist_select.kth_key_u(
+            u, jnp.asarray(seg), ks, tile_n=backend.select_tile_n,
+            use_pallas=True, interpret=backend.interpret)   # (B, S) uint32
 
     def widen(per_seg):             # (B, S) -> (B, n), constant per segment
         return jnp.repeat(per_seg, lens, axis=-1, total_repeat_length=n)

@@ -66,6 +66,7 @@ from ..faults.model import (
 )
 from ..kernels.observe_scatter import observe_scatter
 from ..obs import metrics as obs_metrics
+from ..obs.trace import named_scope
 
 __all__ = [
     "HMUState", "PEBSState", "NBState", "TelemetryBundle",
@@ -445,16 +446,20 @@ def _bundle_observe(bundle: TelemetryBundle, block_ids: jax.Array,
             n_kept = ((cur + m - 1) // per - (cur - 1) // per
                       ).astype(jnp.int32)
             touched = hist > 0
-        return TelemetryBundle(
-            hmu=_hmu_observe(bundle.hmu, block_ids, hist=hist),
-            pebs=(_pebs_apply(bundle.pebs, flat, None, pebs_hist=pebs_hist,
-                              n_kept=n_kept)
-                  if pallas is not None
-                  else _pebs_observe(bundle.pebs, block_ids)),
-            nb=_nb_observe(bundle.nb, block_ids, touched=touched),
-            true_counts=_count_observe(bundle.true_counts, block_ids,
-                                       hist=hist),
-        )
+        with named_scope("telemetry.hmu"):
+            hmu = _hmu_observe(bundle.hmu, block_ids, hist=hist)
+        with named_scope("telemetry.pebs"):
+            pebs = (_pebs_apply(bundle.pebs, flat, None, pebs_hist=pebs_hist,
+                                n_kept=n_kept)
+                    if pallas is not None
+                    else _pebs_observe(bundle.pebs, block_ids))
+        with named_scope("telemetry.nb"):
+            nb = _nb_observe(bundle.nb, block_ids, touched=touched)
+        with named_scope("telemetry.true"):
+            true_counts = _count_observe(bundle.true_counts, block_ids,
+                                         hist=hist)
+        return TelemetryBundle(hmu=hmu, pebs=pebs, nb=nb,
+                               true_counts=true_counts)
     # fault injection: per-batch Bernoulli draws from the model's traced
     # rates.  Ground truth is never faulted — it is the evaluation's
     # reference, not a collector.
@@ -466,21 +471,27 @@ def _bundle_observe(bundle: TelemetryBundle, block_ids: jax.Array,
     if pallas is not None:
         hist, pebs_hist = _fused_scatter(bundle, flat, pallas, keep=keep)
         hit = _pebs_sample_mask(bundle.pebs, m)
-        pebs = _pebs_apply(bundle.pebs, flat, None, pebs_hist=pebs_hist,
-                           n_kept=jnp.sum(hit & keep).astype(jnp.int32))
+        with named_scope("telemetry.pebs"):
+            pebs = _pebs_apply(bundle.pebs, flat, None, pebs_hist=pebs_hist,
+                               n_kept=jnp.sum(hit & keep).astype(jnp.int32))
         n_dropped = jnp.sum(hit & ~keep).astype(jnp.int32)
         touched = hist > 0
     else:
         hist = touched = None
-        pebs, n_dropped = _pebs_observe_faulty(bundle.pebs, block_ids, keep)
+        with named_scope("telemetry.pebs"):
+            pebs, n_dropped = _pebs_observe_faulty(bundle.pebs, block_ids,
+                                                   keep)
+    with named_scope("telemetry.hmu"):
+        hmu = _hmu_observe(bundle.hmu, block_ids,
+                           counter_max=f.hmu_counter_max, hist=hist)
+    with named_scope("telemetry.nb"):
+        nb = _nb_observe(bundle.nb, block_ids, stalled=stalled,
+                         touched=touched)
+    with named_scope("telemetry.true"):
+        true_counts = _count_observe(bundle.true_counts, block_ids,
+                                     hist=hist)
     return TelemetryBundle(
-        hmu=_hmu_observe(bundle.hmu, block_ids,
-                         counter_max=f.hmu_counter_max, hist=hist),
-        pebs=pebs,
-        nb=_nb_observe(bundle.nb, block_ids, stalled=stalled,
-                       touched=touched),
-        true_counts=_count_observe(bundle.true_counts, block_ids,
-                                   hist=hist),
+        hmu=hmu, pebs=pebs, nb=nb, true_counts=true_counts,
         faults=dataclasses.replace(
             f, key=key,
             pebs_dropped=counter_add(f.pebs_dropped, n_dropped),

@@ -238,7 +238,7 @@ def runtime_span_wire(span, scenario: Optional[str] = None) -> dict:
     ``args`` attributes) so this package never imports ``repro.obs``.
     Seconds become the wire's ``_us`` fields; a ``record_sync`` span's
     drained window (``epoch_base``/``n_epochs`` args) rides along so
-    timeline consumers can rebuild the device track."""
+    timeline consumers can tell which epochs each pull drained."""
     rec = {
         "record_type": "runtime_span",
         "schema_version": SCHEMA_VERSION,

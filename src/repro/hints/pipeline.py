@@ -23,6 +23,7 @@ from typing import Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..dlrm.datagen import DLRMTraceSpec, ZipfPageSampler
+from ..obs import trace as obs_trace
 from .providers import (HintLayout, LookaheadWindow, PhaseChangeDetector,
                         StaticTableHints)
 
@@ -74,16 +75,24 @@ class HintPipeline:
     ) -> Tuple[np.ndarray, np.ndarray]:
         """One epoch's refresh: fold ``batches`` into the phase detector and
         return ``(hint_rank, prefetch_rank)`` float32 arrays in [0,1]."""
-        scale = (self.detector.update(batches)
-                 if self.detector is not None else 1.0)
+        _tr = obs_trace.get_tracer()
+        scale = 1.0
+        if self.detector is not None:
+            cm = (_tr.span("hints.detector") if _tr.enabled
+                  else obs_trace.NOOP_SPAN)
+            with cm:
+                scale = self.detector.update(batches)
         if scale != self._scaled[0]:
             self._scaled = (scale, self._static_rank * np.float32(scale))
         hint_rank = self._scaled[1]
         # no-lookahead pipelines hand back the static rank's zero-filled
         # sibling — also cached, so the identity-skip holds there too
-        prefetch_rank = (self.lookahead.rank(upcoming)
-                         if self.lookahead is not None
-                         else self._no_lookahead)
+        prefetch_rank = self._no_lookahead
+        if self.lookahead is not None:
+            cm = (_tr.span("hints.lookahead") if _tr.enabled
+                  else obs_trace.NOOP_SPAN)
+            with cm:
+                prefetch_rank = self.lookahead.rank(upcoming)
         return hint_rank, prefetch_rank
 
     @staticmethod

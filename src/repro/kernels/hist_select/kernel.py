@@ -179,5 +179,6 @@ def kth_key_u_pallas(
             pltpu.VMEM((b, s_pad, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="hist_select",
     )(jax.lax.bitcast_convert_type(u, jnp.int32), seg_ids.reshape(1, n), ks)
     return jax.lax.bitcast_convert_type(out[:, :s, 0], jnp.uint32)

@@ -28,8 +28,7 @@ from .trace import (                                        # noqa: F401
     tracing,
 )
 from .chrometrace import (                                  # noqa: F401
-    chrome_trace_events, device_track_events, pipelining_visible,
-    write_chrome_trace,
+    chrome_trace_events, pipelining_visible, write_chrome_trace,
 )
 
 __all__ = [
@@ -38,6 +37,5 @@ __all__ = [
     "Clock", "CLOCK", "NOOP_SPAN", "NULL_TRACER", "NullTracer", "Span",
     "SpanTracer", "disable", "elapsed_s", "enable", "get_tracer",
     "named_scope", "now_s", "set_tracer", "tracing",
-    "chrome_trace_events", "device_track_events", "pipelining_visible",
-    "write_chrome_trace",
+    "chrome_trace_events", "pipelining_visible", "write_chrome_trace",
 ]

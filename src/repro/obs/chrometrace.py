@@ -3,12 +3,10 @@
 Converts recorded :class:`~repro.obs.trace.Span` objects into the Trace
 Event Format consumed by ``chrome://tracing`` and https://ui.perfetto.dev
 (``{"traceEvents": [...]}`` with ``ph: "X"`` complete events, timestamps
-in microseconds).  Host threads map to tracks by thread name; on top of
-those, :func:`device_track_events` synthesizes a ``device`` track: for each
-``record_sync`` span (one ``jax.device_get`` draining K buffered epochs)
-it draws the interval from the *first drained epoch's* ``observe_all``
-dispatch to the sync's end — the window in which the device stream was
-running ahead of the host.
+in microseconds).  Host threads map to tracks by thread name.  Every event
+is a host span: device time comes from the profiler's own trace (spans
+enter ``jax.profiler.TraceAnnotation`` when ``xla_annotations=True``), not
+from here.
 
 :func:`pipelining_visible` is the structural check behind the PR 6
 pipelining claim, now readable off the timeline: with ``sync_every=K>1``
@@ -22,8 +20,7 @@ import json
 from typing import Dict, Iterable, List, Optional, Sequence
 
 __all__ = [
-    "chrome_trace_events", "device_track_events", "write_chrome_trace",
-    "pipelining_visible",
+    "chrome_trace_events", "write_chrome_trace", "pipelining_visible",
 ]
 
 _PID = 1
@@ -54,44 +51,6 @@ def chrome_trace_events(spans: Sequence, *, t_base: Optional[float] = None,
     return events
 
 
-def _sync_window(sync_span, spans) -> Optional[dict]:
-    """The (t0, t1, epochs) device window one record_sync span drains."""
-    args = sync_span.args or {}
-    base, n = args.get("epoch_base"), args.get("n_epochs")
-    if base is None or n is None:
-        return None
-    starts = [s.t0_s for s in spans
-              if s.name == "observe_all" and s.epoch is not None
-              and base <= s.epoch < base + n]
-    if not starts:
-        return None
-    return {"t0": min(starts), "t1": sync_span.t0_s + sync_span.dur_s,
-            "epoch_base": base, "n_epochs": n}
-
-
-def device_track_events(spans: Sequence, *,
-                        t_base: Optional[float] = None) -> List[dict]:
-    """Synthesized ``device`` track: one span per record_sync window,
-    covering first-drained-epoch dispatch -> sync completion."""
-    base_t = _t_base(spans) if t_base is None else t_base
-    events: List[dict] = []
-    for s in spans:
-        if s.name != "record_sync":
-            continue
-        win = _sync_window(s, spans)
-        if win is None:
-            continue
-        lo, hi = win["epoch_base"], win["epoch_base"] + win["n_epochs"]
-        events.append({
-            "name": f"device epochs [{lo},{hi})", "ph": "X", "cat": "device",
-            "ts": (win["t0"] - base_t) * 1e6,
-            "dur": (win["t1"] - win["t0"]) * 1e6,
-            "pid": _PID, "tid": "device",
-            "args": {"epoch_base": lo, "n_epochs": win["n_epochs"]},
-        })
-    return events
-
-
 def pipelining_visible(spans: Iterable) -> bool:
     """True iff some record_sync span started after the host had already
     dispatched an epoch newer than every epoch that sync drains.
@@ -116,14 +75,11 @@ def pipelining_visible(spans: Iterable) -> bool:
     return False
 
 
-def write_chrome_trace(path, spans: Sequence, *, device_track: bool = True,
+def write_chrome_trace(path, spans: Sequence, *,
                        metadata: Optional[dict] = None) -> dict:
     """Write ``{"traceEvents": [...]}`` JSON for chrome://tracing; returns
     the document (also handy for asserting on it in tests)."""
-    base = _t_base(spans)
-    events = chrome_trace_events(spans, t_base=base)
-    if device_track:
-        events.extend(device_track_events(spans, t_base=base))
+    events = chrome_trace_events(spans)
     doc: Dict[str, object] = {
         "traceEvents": sorted(events, key=lambda e: (e["ts"], e["tid"])),
         "displayTimeUnit": "ms",

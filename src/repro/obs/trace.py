@@ -8,7 +8,10 @@ change that.  Two tracers implement the same surface:
   monotonic clock, thread name (the chrome-trace track), nesting depth, and
   optional args such as the epoch index.  When ``xla_annotations=True`` each
   span also enters ``jax.profiler.TraceAnnotation`` so the same names land
-  in XLA profiler timelines.
+  in XLA profiler timelines, with the epoch and args as event stats (the
+  profiler encodes them as ``name#k=v,...#``, so ``,``, ``#`` and ``=``
+  in a value become ``+``, ``_`` and ``:`` there; the span keeps the
+  value as given).
 * :class:`NullTracer` (``enabled = False``, module default) returns one
   shared no-op context manager from every ``span()`` call — zero
   allocations per epoch, no clock reads, nothing retained.
@@ -126,6 +129,16 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
+_ANNOTATION_ESCAPES = str.maketrans({",": "+", "#": "_", "=": ":"})
+
+
+def _annotation_value(value):
+    """``value`` as a profiler event stat: a string loses the characters
+    of the ``name#k=v,...#`` encoding, which would split it."""
+    return (value.translate(_ANNOTATION_ESCAPES) if isinstance(value, str)
+            else value)
+
+
 class _SpanCtx:
     """Context manager recording one Span into its tracer."""
 
@@ -145,7 +158,12 @@ class _SpanCtx:
         if tr.xla_annotations:
             try:
                 import jax
-                self._ann = jax.profiler.TraceAnnotation(self._name)
+                # epoch and args become event stats of the profiler event
+                kw = {k: _annotation_value(v)
+                      for k, v in (self._args or {}).items()}
+                if self._epoch is not None:
+                    kw["epoch"] = self._epoch
+                self._ann = jax.profiler.TraceAnnotation(self._name, **kw)
                 self._ann.__enter__()
             except Exception:            # profiler unavailable -> host-only
                 self._ann = None

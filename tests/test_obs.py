@@ -11,9 +11,15 @@ watching the runtime must cost the watched system nothing:
 * **Tracer** — nestable spans over an injectable clock (exact durations
   with a fake clock), the NOOP_SPAN singleton identity, and a
   tracemalloc-verified zero-allocation disabled hot loop.
-* **Timeline** — chrome trace-event conversion, the synthesized device
-  track, and ``pipelining_visible``: structurally True for a
-  ``sync_every=K>1`` span pattern, False for K=1.
+* **Timeline** — chrome trace-event conversion (host spans only) and
+  ``pipelining_visible``: structurally True for a ``sync_every=K>1`` span
+  pattern, False for K=1.
+* **Named work** — the compiled epoch programs carry the ``selectk.*``,
+  ``placement.free_slots`` and ``telemetry.*`` scopes and the same ops
+  without them; a traced fused epoch records one ``id_upload``,
+  ``record_wait``, ``record_pull`` and ``record_assembly`` (and, hinted,
+  ``hints``, ``hints.detector``, ``hints.lookahead``), with span args
+  reaching ``jax.profiler.TraceAnnotation``.
 * **Integration** — an enabled-tracer runtime run produces exactly the
   expected spans with zero added dispatches and bit-identical records vs
   disabled; runtime_span/runtime_metric wire records validate against the
@@ -287,12 +293,6 @@ class TestChromeTrace:
         ]
         assert not chrometrace.pipelining_visible(serial)
 
-    def test_device_track_covers_sync_window(self):
-        (e,) = chrometrace.device_track_events(pipelined_spans())
-        assert e["tid"] == "device" and e["name"] == "device epochs [0,2)"
-        # first drained epoch's dispatch (t=0.0) -> sync end (t=2.7)
-        assert e["ts"] == 0.0 and e["dur"] == pytest.approx(2.7e6)
-
     def test_write_chrome_trace_round_trip(self, tmp_path):
         path = tmp_path / "trace.json"
         doc = chrometrace.write_chrome_trace(
@@ -302,7 +302,8 @@ class TestChromeTrace:
         assert doc["displayTimeUnit"] == "ms"
         assert doc["otherData"] == {"bench": "test"}
         tids = {e["tid"] for e in doc["traceEvents"]}
-        assert tids == {"host", "device"}
+        assert tids == {"host"}            # host spans only, no device track
+        assert len(doc["traceEvents"]) == len(pipelined_spans())
         ts = [e["ts"] for e in doc["traceEvents"]]
         assert ts == sorted(ts)
 
@@ -513,3 +514,267 @@ class TestRuntimeIntegration:
         *_, tracer = runs
         for s in tracer.spans:
             validate_record(runtime_span_wire(s, scenario="test"))
+
+
+# ------------------------------------------------------- named scopes (HLO)
+EPOCH_STEP_SCOPES = ("selectk.threshold", "selectk.mask", "selectk.compact",
+                     "selectk.order", "placement.free_slots")
+COLLECTOR_SCOPES = ("telemetry.true", "telemetry.hmu", "telemetry.pebs",
+                    "telemetry.nb")
+
+
+def _scope_names(hlo_text):
+    """Every component of every ``op_name`` in compiled HLO text."""
+    import re
+    return {part for name in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for part in name.split("/")}
+
+
+def _without_metadata(hlo_text):
+    """Compiled HLO with op metadata and the trailing debug tables cut."""
+    import re
+    return re.sub(r", metadata=\{[^}]*\}", "",
+                  hlo_text.split("\nFileNames")[0])
+
+
+def _compiled_programs(rt, faults=False):
+    """(_epoch_step, observe_all) compiled for ``rt``'s state at its size,
+    freshly traced (new callables, so no cached trace is reused)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import telemetry as tel
+    from repro.faults import FaultModel
+    state = rt._state
+    bundle = state.bundle
+    if faults:
+        bundle = tel.bundle_init(rt.n_blocks, pebs_period=8,
+                                 faults=FaultModel.create(pebs_drop_p=0.1))
+    step_fn = rtmod._epoch_step.__wrapped__
+    obs_fn = tel.observe_all.__wrapped__
+    step = jax.jit(lambda *a, **k: step_fn(*a, **k),
+                   static_argnames=("cfg", "s_max")).lower(
+        state, jnp.int32(4 * 512), jnp.int32(0), cfg=rt._cfg,
+        s_max=64).compile().as_text()
+    observe = jax.jit(lambda *a, **k: obs_fn(*a, **k),
+                      static_argnames=("pallas",)).lower(
+        bundle, jnp.zeros((4, 512), jnp.int32),
+        pallas=rt._pallas).compile().as_text()
+    return step, observe
+
+
+class TestNamedScopes:
+    N, K = 2048, 128
+
+    @pytest.fixture(scope="class")
+    def compiled(self):
+        rt = EpochRuntime(self.N, self.K, pebs_period=8,
+                          nb_scan_rate=self.N // 4, fused=True)
+        return {"plain": _compiled_programs(rt),
+                "faults": _compiled_programs(rt, faults=True)[1], "rt": rt}
+
+    @pytest.mark.parametrize("scope", EPOCH_STEP_SCOPES)
+    def test_epoch_step_hlo_names_the_scope(self, compiled, scope):
+        step, _ = compiled["plain"]
+        assert scope in _scope_names(step)
+
+    @pytest.mark.parametrize("branch", ("plain", "faults"))
+    @pytest.mark.parametrize("scope", COLLECTOR_SCOPES)
+    def test_observe_all_hlo_names_each_collector(self, compiled, scope,
+                                                  branch):
+        observe = (compiled["plain"][1] if branch == "plain"
+                   else compiled["faults"])
+        assert scope in _scope_names(observe)
+
+    def test_scopes_leave_the_compiled_ops_alone(self, compiled,
+                                                 monkeypatch):
+        import contextlib
+        from repro.core import placement, selectk
+        from repro.core import telemetry as tel
+        for mod in (selectk, placement, tel):
+            monkeypatch.setattr(mod, "named_scope",
+                                lambda name: contextlib.nullcontext())
+        bare = _compiled_programs(compiled["rt"])
+        for scoped, plain in zip(compiled["plain"], bare):
+            assert not _scope_names(plain) & set(EPOCH_STEP_SCOPES
+                                                 + COLLECTOR_SCOPES)
+            assert _without_metadata(scoped) == _without_metadata(plain)
+
+    def test_hist_select_kernel_is_named(self):
+        import jax
+        import jax.numpy as jnp
+        from repro.kernels.hist_select.kernel import kth_key_u_pallas
+        jaxpr = jax.make_jaxpr(lambda u: kth_key_u_pallas(
+            u, jnp.zeros((256,), jnp.int32), jnp.asarray([3]), tile_n=128,
+            interpret=True))(jnp.zeros((2, 256), jnp.uint32))
+        (eqn,) = [e for e in jaxpr.jaxpr.eqns
+                  if e.primitive.name == "pallas_call"]
+        assert eqn.params["name"] == "hist_select"
+
+
+# ----------------------------------------------------- runtime's finer spans
+def _hinted_run(n, k, eps):
+    from repro.hints import HintPipeline, LookaheadWindow, PhaseChangeDetector
+    pipe = HintPipeline(n, static=np.linspace(1, 0, n, dtype=np.float32),
+                        lookahead=LookaheadWindow(n, depth=1),
+                        detector=PhaseChangeDetector(n))
+    rt = EpochRuntime(n, k, pebs_period=8, nb_scan_rate=n // 4, fused=True,
+                      hints=pipe)
+    for i, ep in enumerate(eps):
+        rt.step(ep, lookahead=eps[i + 1:i + 2])
+    return rt
+
+
+class TestRuntimeSpans:
+    N, K, EPOCHS = 512, 64, 3
+    PER_EPOCH = ("hints", "hints.detector", "hints.lookahead", "id_upload",
+                 "observe_all", "epoch_step", "record_sync", "record_wait",
+                 "record_pull", "record_assembly")
+
+    @pytest.fixture(scope="class")
+    def runs(self):
+        rng = np.random.default_rng(11)
+        eps = [(rng.zipf(1.2, size=(2, 256)) % self.N).astype(np.int32)
+               for _ in range(self.EPOCHS)]
+        _hinted_run(self.N, self.K, eps)               # warm the jit caches
+        obs_trace.disable()
+        off = _hinted_run(self.N, self.K, eps)
+        with tracing() as tracer:
+            on = _hinted_run(self.N, self.K, eps)
+        return off, on, tracer, eps
+
+    @pytest.mark.parametrize("name", PER_EPOCH)
+    def test_one_span_per_fused_epoch(self, runs, name):
+        *_, tracer, _ = runs
+        assert sum(s.name == name for s in tracer.spans) == self.EPOCHS
+
+    def test_wait_and_pull_nest_inside_record_sync(self, runs):
+        *_, tracer, _ = runs
+        syncs = [s for s in tracer.spans if s.name == "record_sync"]
+        for name in ("record_wait", "record_pull"):
+            for s in (s for s in tracer.spans if s.name == name):
+                (outer,) = [o for o in syncs
+                            if o.t0_s <= s.t0_s
+                            and s.t0_s + s.dur_s <= o.t0_s + o.dur_s]
+                assert s.depth == outer.depth + 1
+                assert s.args == {"epoch_base": outer.args["epoch_base"]}
+        for s in (s for s in tracer.spans if s.name == "record_assembly"):
+            assert s.depth == 0 and s.args["n_epochs"] == 1
+
+    def test_hint_providers_nest_inside_hints(self, runs):
+        *_, tracer, _ = runs
+        hints = [s for s in tracer.spans if s.name == "hints"]
+        assert [s.epoch for s in hints] == list(range(self.EPOCHS))
+        for s in tracer.spans:
+            if s.name.startswith("hints."):
+                assert any(h.t0_s <= s.t0_s
+                           and s.t0_s + s.dur_s <= h.t0_s + h.dur_s
+                           and s.depth == h.depth + 1 for h in hints)
+
+    def test_id_upload_carries_the_bytes(self, runs):
+        *_, tracer, eps = runs
+        ups = [s for s in tracer.spans if s.name == "id_upload"]
+        assert [s.epoch for s in ups] == list(range(self.EPOCHS))
+        assert [s.args["bytes"] for s in ups] == [ep.nbytes for ep in eps]
+        # the upload comes before, not inside, the observe_all dispatch
+        obs = [s for s in tracer.spans if s.name == "observe_all"]
+        for u, o in zip(ups, obs):
+            assert u.t0_s + u.dur_s <= o.t0_s and u.depth == o.depth
+
+    def test_records_bit_identical_tracing_on_and_off(self, runs):
+        off, on, *_ = runs
+        for lane in off.records:
+            assert ([r.to_dict() for r in off.records[lane]]
+                    == [r.to_dict() for r in on.records[lane]])
+            assert np.array_equal(off.lanes[lane].slot_to_block,
+                                  on.lanes[lane].slot_to_block)
+
+
+def test_span_args_reach_trace_annotation(monkeypatch):
+    import jax
+    seen = []
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            seen.append((name, kwargs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    tr = SpanTracer(clock=FakeClock(), xla_annotations=True)
+    with tr.span("id_upload", epoch=4, bytes=1024):
+        pass
+    with tr.span("record_sync", epoch_base=2, n_epochs=1):
+        pass
+    with tr.span("hints.detector"):
+        pass
+    with tr.span("hint_refresh", epoch=1, arrays="hint_rank,prefetch_rank"):
+        pass
+    # the profiler's name#k=v,...# encoding would split a comma; the
+    # span itself keeps the value as given
+    assert seen == [("id_upload", {"bytes": 1024, "epoch": 4}),
+                    ("record_sync", {"epoch_base": 2, "n_epochs": 1}),
+                    ("hints.detector", {}),
+                    ("hint_refresh", {"arrays": "hint_rank+prefetch_rank",
+                                      "epoch": 1})]
+    assert tr.spans[-1].args == {"arrays": "hint_rank,prefetch_rank"}
+
+
+SPAN_SITES = ("hints", "hints.detector", "hints.lookahead", "id_upload",
+              "observe_all", "epoch_step", "record_sync", "record_wait",
+              "record_pull", "record_assembly")
+
+
+def _span_site_lines(path):
+    """Line numbers in ``path`` that look up the tracer, build a guarded
+    span or enter one."""
+    with open(path) as f:
+        return {i for i, line in enumerate(f, 1)
+                if "_tr" in line or "NOOP_SPAN" in line or "with cm" in line}
+
+
+@pytest.fixture(scope="module")
+def disabled_steps():
+    """Hinted fused epochs with tracing off, the last ones under
+    tracemalloc: the span names the disabled tracer was asked for, and
+    the allocations still held afterwards that pass through a span site
+    of the runtime or the hint pipeline, or through the tracer module."""
+    from repro.hints import pipeline
+    n, k = 512, 64
+    rng = np.random.default_rng(5)
+    eps = [(rng.zipf(1.2, size=(2, 256)) % n).astype(np.int32)
+           for _ in range(6)]
+    obs_trace.disable()
+    asked = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(obs_trace.NullTracer, "span",
+                   lambda self, name, **kw: asked.append(name) or NOOP_SPAN)
+        rt = _hinted_run(n, k, eps[:2])                # compiled and warm
+        tracemalloc.start(64)
+        try:
+            before = tracemalloc.take_snapshot()
+            for i in range(2, len(eps)):
+                rt.step(eps[i], lookahead=eps[i + 1:i + 2])
+            after = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+    sites = {path: _span_site_lines(path)
+             for path in (rtmod.__file__, pipeline.__file__)}
+    held = [st for st in after.compare_to(before, "traceback")
+            if st.size_diff > 0 and any(
+                fr.filename == obs_trace.__file__
+                or fr.lineno in sites.get(fr.filename, ())
+                for fr in st.traceback)]
+    return asked, held
+
+
+@pytest.mark.parametrize("name", SPAN_SITES)
+def test_disabled_new_span_sites_allocate_nothing(disabled_steps, name):
+    asked, held = disabled_steps
+    # the guard skips the span() call, kwargs and all ...
+    assert name not in asked
+    # ... and nothing the span sites or the tracer allocate is kept
+    assert held == []
