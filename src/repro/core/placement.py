@@ -155,7 +155,8 @@ def apply_plan(p: Placement, want: jax.Array, est: jax.Array,
     new_rank = jnp.cumsum(new.astype(jnp.int32), axis=-1) - 1
     assign = new & (new_rank < n_free)
     with named_scope("placement.free_slots"):
-        free_slot = selectk.compact(cfree, k)       # (..., k), fill -> k
+        # (..., k), fill -> k
+        free_slot = selectk.compact(cfree, k, site="free_slots")
     slot_for = jnp.take_along_axis(
         free_slot, jnp.clip(new_rank, 0, k - 1), axis=-1)
     s2b = _scatter_ids(s2b, slot_for, assign, want)

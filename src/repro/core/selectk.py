@@ -9,8 +9,13 @@ per million on the same backend):
 
 * :func:`select_top_k` — bit-identical replacement for ``lax.top_k(key, k)``
   (values descending, ties broken lowest-index-first): a 32-step bitwise
-  binary search finds the k-th largest key, a cumsum+searchsorted compacts
-  the selected indices, and only the k survivors are sorted.
+  binary search finds the k-th largest key, :func:`compact` gathers the
+  selected indices from the mask's prefix count, and only the k survivors
+  are sorted.
+* :func:`compact` — the first k selected indices of a prefix count: one
+  scatter pass over n (each selected index written to slot ``count - 1``)
+  where ``k * ceil(log2(n + 1)) >= n / SCATTER_C``, a ``searchsorted`` of
+  the k targets below that (:func:`compact_impl`, static shapes only).
 * :func:`top_k_mask` — membership mask of the same selection, for consumers
   that need set intersections (epoch-hot scoring) rather than order.
 * :func:`stable_rank_sparse` — ``argsort(argsort(x))`` for non-negative
@@ -35,6 +40,7 @@ here instead of quietly taking the XLA search.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import numpy as np
@@ -43,11 +49,12 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import hist_select
+from ..obs import metrics as obs_metrics
 from ..obs.trace import named_scope
 
 __all__ = [
     "sortable_key", "select_top_k", "top_k_mask", "stable_rank_sparse",
-    "compact", "segment_top_k_mask",
+    "compact", "compact_impl", "segment_top_k_mask",
 ]
 
 _SIGN = jnp.uint32(0x80000000)
@@ -173,11 +180,51 @@ def bottom_k_mask(key: jax.Array, counts) -> jax.Array:
     return _selection_mask(~_to_u(key), counts)[0]
 
 
-def compact(csel: jax.Array, k: int) -> jax.Array:
+# The rule's constant: a v5e gathers one element of one binary-search round
+# in 13-19 ns where the scatter below writes one update in 4.8-6.3 ns, so
+# the scatter wins once k * ceil(log2(n + 1)) reaches about n / 3
+# (benchmarks/compact_crossover.py: the two meet at n / 3.0 for
+# n = 5,000,000 and at n / 3.8 for n = 2,621,440).
+SCATTER_C = 3
+
+# Trace-time counter: which algorithm each named compaction site compiled
+# to, one tick per trace (the epoch loop traces once).
+COMPACT_IMPL = obs_metrics.REGISTRY.counter(
+    "repro_compact_impl_total",
+    help="Traces of a compaction site, by the algorithm its shape chose")
+
+
+def compact_impl(n: int, k: int) -> str:
+    """``"scatter"`` (n updates per row) when ``k * ceil(log2(n + 1))``
+    binary-search gathers reach ``n / SCATTER_C``, else ``"search"``.
+    Static shapes only."""
+    return ("scatter" if SCATTER_C * k * math.ceil(math.log2(n + 1)) >= n
+            else "search")
+
+
+def compact(csel: jax.Array, k: int, *, site: Optional[str] = None
+            ) -> jax.Array:
     """Indices of the first k selected elements in ascending order, given the
     inclusive prefix count of a selection mask along the last axis (fewer
-    than k true entries fill with n).  Shared by :func:`select_top_k` and
-    ``placement.apply_plan``'s free-slot assignment."""
+    than k true entries fill with n).  Shared by :func:`select_top_k`,
+    ``placement.apply_plan``'s free-slot assignment and
+    :func:`stable_rank_sparse`.
+
+    The j-th selected element is the position i where the count steps up to
+    ``csel[i] == j + 1``, so one pass that writes each such i to slot
+    ``csel[i] - 1`` answers in n updates per row; a binary search of
+    ``csel`` for each target 1..k takes ``k * ceil(log2(n + 1))`` gathers.
+    :func:`compact_impl` picks by shape; both give the same indices.
+    ``site`` names the call site in ``repro_compact_impl_total``."""
+    impl = compact_impl(csel.shape[-1], k)
+    if site is not None:
+        COMPACT_IMPL.labels(site=site, impl=impl).inc()
+    if impl == "scatter":
+        return _compact_scatter(csel, k)
+    return _compact_search(csel, k)
+
+
+def _compact_search(csel: jax.Array, k: int) -> jax.Array:
     targets = jnp.arange(1, k + 1, dtype=csel.dtype)
 
     def pick(cs):
@@ -186,6 +233,25 @@ def compact(csel: jax.Array, k: int) -> jax.Array:
     for _ in range(csel.ndim - 1):
         pick = jax.vmap(pick)
     return pick(csel)
+
+
+def _compact_scatter(csel: jax.Array, k: int) -> jax.Array:
+    n = csel.shape[-1]
+    iota = jnp.arange(n, dtype=jnp.int32)
+
+    def row(cs):
+        prev = jnp.concatenate([jnp.zeros((1,), cs.dtype), cs[:-1]])
+        dest = jnp.where((cs > prev) & (cs <= k), cs - 1, k)   # k: dropped
+        return jnp.full((k,), n, jnp.int32).at[dest].set(iota, mode="drop")
+
+    # One row at a time, the mask recovered from the count inside the row:
+    # compiled for a v5e at both benchmark cells' sizes this adds no
+    # temporary to the epoch step, where a batched scatter's (rows, n, 2)
+    # index tuples take 480 MB at the DLRM select's shape, and a mask passed
+    # in from outside the loop raises the step's peak by 46 MB at
+    # mmap-bench's.
+    out = jax.lax.map(row, csel.reshape((-1, n)))
+    return out.reshape(csel.shape[:-1] + (k,))
 
 
 def select_top_k(key: jax.Array, k: int, return_mask: bool = False,
@@ -199,7 +265,7 @@ def select_top_k(key: jax.Array, k: int, return_mask: bool = False,
     u = _to_u(key)
     sel, csel = _selection_mask(u, k, backend)
     with named_scope("selectk.compact"):
-        ids = compact(csel, k)                    # ascending index order
+        ids = compact(csel, k, site="select")     # ascending index order
 
     def order(us, i):
         # ascending ~u == descending u; stable keeps ascending-index ties
@@ -292,9 +358,7 @@ def stable_rank_sparse(x: jax.Array, max_positive: int) -> jax.Array:
     pos = x > 0
     n_zero = n - jnp.sum(pos.astype(jnp.int32))
     rank = prefix_sum(~pos) - 1                          # zero ranks
-    cpos = prefix_sum(pos)
-    ids = jnp.searchsorted(cpos, jnp.arange(1, s + 1, dtype=cpos.dtype),
-                           side="left").astype(jnp.int32)  # fill -> n
+    ids = compact(prefix_sum(pos), s)                    # fill -> n
     vals = jnp.where(ids < n, x[jnp.minimum(ids, n - 1)], jnp.iinfo(jnp.int32).max)
     _, ids_sorted = jax.lax.sort_key_val(_to_u(vals), ids, is_stable=True)
     return rank.at[jnp.where(ids_sorted < n, ids_sorted, n)].set(

@@ -599,6 +599,18 @@ class TestNamedScopes:
                                                  + COLLECTOR_SCOPES)
             assert _without_metadata(scoped) == _without_metadata(plain)
 
+    @pytest.mark.parametrize("scope", ("selectk.compact",
+                                       "placement.free_slots"))
+    def test_compaction_scatter_sits_in_its_scope(self, compiled, scope):
+        """At this size both compactions take the scatter path, and the
+        scatter op itself carries the scope the benchmark reads."""
+        import re
+        step, _ = compiled["plain"]
+        names = [re.search(r'op_name="([^"]*)"', line).group(1)
+                 for line in step.splitlines()
+                 if re.search(r"\bscatter\(", line) and "op_name=" in line]
+        assert any(scope in name.split("/") for name in names), names
+
     def test_hist_select_kernel_is_named(self):
         import jax
         import jax.numpy as jnp
@@ -609,6 +621,33 @@ class TestNamedScopes:
         (eqn,) = [e for e in jaxpr.jaxpr.eqns
                   if e.primitive.name == "pallas_call"]
         assert eqn.params["name"] == "hist_select"
+
+
+# ------------------------------------------------ compaction algorithm count
+def test_compact_impl_counter_ticks_once_per_trace_per_site():
+    """At DLRM SMALL (5,000 pages) both compaction sites take the scatter
+    path: one tick each per trace of the epoch step, none per epoch."""
+    import itertools
+    from repro.core import selectk
+    from repro.dlrm import datagen
+    from repro.scenarios import DLRMScenario
+    # a k_hot no other test uses, so this process traces the step afresh
+    scn = DLRMScenario(spec=datagen.SMALL, n_epochs=3, k_hot=251)
+    rt = EpochRuntime.for_scenario(scn, policies=("hmu_oracle", "hinted"))
+    cells = {(site, impl): selectk.COMPACT_IMPL.labels(site=site, impl=impl)
+             for site, impl in itertools.product(("select", "free_slots"),
+                                                 ("scatter", "search"))}
+    before = {key: c.value for key, c in cells.items()}
+    with rtmod.counting() as c:
+        for batches in scn.epochs():
+            rt.step(batches)
+        rt.flush()
+    assert c.trace["epoch_step"] == 1
+    ticks = {key: cells[key].value - before[key] for key in cells}
+    assert ticks == {("select", "scatter"): 1, ("select", "search"): 0,
+                     ("free_slots", "scatter"): 1,
+                     ("free_slots", "search"): 0}
+    assert selectk.compact_impl(scn.n_blocks, scn.k_hot) == "scatter"
 
 
 # ----------------------------------------------------- runtime's finer spans

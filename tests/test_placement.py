@@ -80,6 +80,75 @@ def test_stable_rank_sparse_matches_double_argsort():
         np.testing.assert_array_equal(ref, got)
 
 
+def _counts(n_sel, n, seed):
+    """(..., n) inclusive prefix counts of masks with ``n_sel[row]`` true
+    entries at random positions (one row per entry of ``n_sel``)."""
+    rng = np.random.default_rng(seed)
+    rows = np.zeros((len(n_sel), n), bool)
+    for r, m in enumerate(n_sel):
+        rows[r, rng.choice(n, m, replace=False)] = True
+    return jnp.asarray(np.cumsum(rows, axis=-1, dtype=np.int32))
+
+
+def _searchsorted_rows(csel, k):
+    targets = jnp.arange(1, k + 1, dtype=csel.dtype)
+    rows = csel.reshape((-1, csel.shape[-1]))
+    return np.stack([np.asarray(jnp.searchsorted(r, targets, side="left"))
+                     for r in rows]).reshape(csel.shape[:-1] + (k,))
+
+
+COMPACT_CASES = {        # name: (selected per row, n, k, batched)
+    "fewer_than_k": ((50,), 1_000, 200, False),    # fills with n
+    "exactly_k": ((100,), 1_000, 100, False),
+    "more_than_k": ((300,), 1_000, 60, False),
+    "batched": ((100, 150, 400), 1_500, 150, True),
+    "k_eq_n": ((150, 512), 512, 512, True),        # the free-slot shape
+    "n_1_selected": ((1,), 1, 1, False),
+    "n_1_empty": ((0,), 1, 1, False),
+}
+
+
+@pytest.mark.parametrize("impl", ["scatter", "search"])
+@pytest.mark.parametrize("case", sorted(COMPACT_CASES))
+def test_compact_paths_match_searchsorted(case, impl):
+    n_sel, n, k, batched = COMPACT_CASES[case]
+    csel = _counts(n_sel, n, seed=len(case))
+    if not batched:
+        csel = csel[0]
+    fn = {"scatter": lambda c: selectk._compact_scatter(c, k),
+          "search": lambda c: selectk._compact_search(c, k)}[impl]
+    got = np.asarray(jax.jit(fn)(csel))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(_searchsorted_rows(csel, k), got)
+
+
+@pytest.mark.parametrize("n,k,impl", [
+    (4_000, 400, "scatter"),
+    (4_000, 10, "search"),
+    (512, 512, "scatter"),
+])
+def test_compact_takes_the_path_its_shape_picks(n, k, impl):
+    assert selectk.compact_impl(n, k) == impl
+    csel = _counts((k // 2, k, min(2 * k, n)), n, seed=n + k)
+    np.testing.assert_array_equal(
+        _searchsorted_rows(csel, k),
+        np.asarray(jax.jit(lambda c: selectk.compact(c, k))(csel)))
+
+
+@pytest.mark.parametrize("n,k,impl", [
+    (5_000_000, 486_587, "scatter"),    # DLRM select
+    (486_587, 486_587, "scatter"),      # DLRM free slots
+    (2_621_440, 262_144, "scatter"),    # mmap-bench select
+    (5_000_000, 32_768, "search"),      # stable_rank_sparse's bound, DLRM
+    (2_621_440, 16_384, "search"),      # ... and mmap-bench
+    (5_000_000, 72_464, "scatter"),     # either side of n / (3 * 23)
+    (5_000_000, 72_463, "search"),
+    (1, 1, "scatter"),
+])
+def test_compact_rule_crossover(n, k, impl):
+    assert selectk.compact_impl(n, k) == impl
+
+
 def test_prefix_sum_matches_cumsum():
     rng = np.random.default_rng(5)
     for shape in ((1_024,), (3, 2_048), (5, 1_000)):   # incl. fallback path
