@@ -4,12 +4,13 @@
     python3 bench/control.py --workload mmap_paper.scan --epochs 300 \
         --seeds 11 12 13
 
-The control is the plain reference computed with bfloat16 scores (the
-configuration states float32), put in the program's place: for each seed it
-replays ``--epochs`` epochs of the cell's pool through the reference and
-through the control, and compares the two exactly as a run compares the
-program with the reference.  A sound limit lies below every number printed
-here.  The benchmark's own runs never run this.
+The control is the plain reference of the configuration's harness module
+computed one precision step down (for ``single``: bfloat16 scores, where
+the configuration states float32), put in the program's place: for each
+seed it replays ``--epochs`` epochs of the cell's pool through the
+reference and through the control, and compares the two exactly as a run
+compares the program with the reference.  A sound limit lies below every
+number printed here.  The benchmark's own runs never run this.
 """
 from __future__ import annotations
 
@@ -24,21 +25,17 @@ sys.path.insert(0, str(BENCH.parent / "src"))
 
 import numpy as np  # noqa: E402
 
-from reference import run_reference  # noqa: E402
-from run_cell import (REHEARSAL_BLOCKS, compare, load_cell,  # noqa: E402
-                      shrink)
-from traffic import make_pool  # noqa: E402
+from run_cell import REHEARSAL_BLOCKS, compare, load_cell  # noqa: E402
 
 
 def control_readings(spec: dict, seed: int, n_epochs: int) -> dict:
     config, traffic = spec["config"], spec["traffic"]
-    pool = make_pool(config, traffic, seed)
-    ref, ref_place = run_reference(config, traffic, pool, n_epochs)
-    ctl, ctl_place = run_reference(config, traffic, pool, n_epochs,
-                                   control=True)
+    harness = spec["harness"]
+    pool = harness.make_pool(config, traffic, seed)
+    ref = harness.reference(config, traffic, pool, n_epochs)
+    ctl = harness.reference(config, traffic, pool, n_epochs, control=True)
     bad_fields, bad_slots, failed = compare(
-        config, ctl, ctl_place, ref, ref_place,
-        int(traffic["warmup_epochs"]))
+        ctl, ref, int(traffic["warmup_epochs"]))
     return {"record_mismatches": bad_fields,
             "placement_mismatches": bad_slots, "failed_epochs": failed}
 
@@ -51,10 +48,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU run at a reduced size")
     args = ap.parse_args(argv)
-    spec = load_cell(args.workload)
-    if args.rehearse:
-        spec["config"], spec["traffic"] = shrink(
-            spec["config"], spec["traffic"], REHEARSAL_BLOCKS)
+    spec = load_cell(args.workload,
+                     REHEARSAL_BLOCKS if args.rehearse else None)
     rows = []
     for seed in args.seeds:
         r = control_readings(spec, seed, args.epochs)

@@ -1,10 +1,8 @@
 """Host time per epoch in the lookahead window's rank (the runtime's
 ``hints.lookahead`` span inside ``HintPipeline.epoch_ranks``),
 milliseconds."""
-import trace_scopes
 
 
 def read(trace):
-    t = trace_scopes.of(trace)
-    return None if t is None else trace_scopes.per_epoch_ms(
-        t.span_s("hints.lookahead"), t)
+    s = trace.span_s("hints.lookahead")
+    return s / trace.n_epochs * 1e3 if s else None

@@ -1,10 +1,8 @@
 """Device time per epoch of ``placement.apply_plan``'s free-slot
 compaction (scope ``placement.free_slots`` inside ``jit__epoch_step``),
 milliseconds."""
-import trace_scopes
 
 
 def read(trace):
-    t = trace_scopes.of(trace)
-    return None if t is None else trace_scopes.per_epoch_ms(
-        t.scope_s("placement.free_slots", "jit__epoch_step"), t)
+    s = trace.scope_s("placement.free_slots", "jit__epoch_step")
+    return s / trace.n_epochs * 1e3 if s else None

@@ -1,10 +1,7 @@
-"""Device time per epoch of the select's compaction (the ``searchsorted``
-over the selection's prefix count, scope ``selectk.compact`` inside
-``jit__epoch_step``), milliseconds."""
-import trace_scopes
+"""Device time per epoch of the select's compaction (scope
+``selectk.compact`` inside ``jit__epoch_step``), milliseconds."""
 
 
 def read(trace):
-    t = trace_scopes.of(trace)
-    return None if t is None else trace_scopes.per_epoch_ms(
-        t.scope_s("selectk.compact", "jit__epoch_step"), t)
+    s = trace.scope_s("selectk.compact", "jit__epoch_step")
+    return s / trace.n_epochs * 1e3 if s else None

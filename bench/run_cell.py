@@ -8,18 +8,20 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 (``bench/configs/<config>.json``, the deployment) under a traffic mix
 (``bench/traffic/<traffic>.json``).  One run:
 
-1. **Set-up** -- places the persistent compilation cache, draws the cell's
-   seeded pool of epochs on the host, builds ``EpochRuntime.for_scenario``
-   on the default path (fused, the platform's kernels, hints when the mix
-   has them) and serves ``warmup_epochs`` epochs so every program the
-   window runs is compiled.
+1. **Set-up** -- places the persistent compilation cache, loads the
+   configuration's harness module (``bench/harness/<name>.py``, named by
+   its ``"harness"`` key, ``single`` without one), which draws the cell's
+   seeded pool of epochs on the host and builds the ``EpochRuntime`` on
+   the default path, and serves ``warmup_epochs`` epochs so every program
+   the window runs is compiled.
 2. **Window** -- hands pool epochs to ``EpochRuntime.step`` in order,
    cyclically, one in flight, until ``--seconds`` have passed; then flushes
    and blocks until the device is done.  With ``--trace 1`` the window runs
    under the profiler and the per-layer metrics are read from its trace.
-3. **Check** -- reads the device's peak memory, frees the runtime, replays
-   every served epoch through the plain reference (``bench/reference.py``)
-   and compares every record of every lane and the final placements.
+3. **Check** -- takes the program's rows and placements, reads the
+   device's peak memory, frees the runtime, replays every served epoch
+   through the harness module's plain reference and compares every field
+   of every row of every stream, and every placement, exactly.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` when
@@ -38,12 +40,13 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
+import itertools  # noqa: E402
 import json  # noqa: E402
 import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Dict, List  # noqa: E402
+from typing import Dict, List, Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -51,11 +54,9 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
-from reference import RECORD_FIELDS, run_reference  # noqa: E402
-from traffic import make_pool  # noqa: E402
 import trace_reduce  # noqa: E402
 
-TRACE_DIR = ROOT / "bench_out" / "trace"
+TRACE_DIR = trace_reduce.TRACE_DIR
 REHEARSAL_BLOCKS = 20_000
 
 
@@ -72,8 +73,10 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
-def load_cell(name: str) -> dict:
-    """The cell's entry, configuration, traffic mix and metric lists."""
+def load_cell(name: str, n_blocks: Optional[int] = None) -> dict:
+    """The cell's entry, configuration, harness module, traffic mix and
+    metric lists; at ``n_blocks`` blocks where given (rehearsals, tests),
+    as the harness module shrinks it."""
     bench = load_json(ROOT / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -84,27 +87,16 @@ def load_cell(name: str) -> dict:
     def applies(metric: dict) -> bool:
         return "workloads" not in metric or name in metric["workloads"]
 
+    config = load_json(ROOT / conf["file"])
+    traffic = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
+    harness = load_harness(config.get("harness", "single"))
+    if n_blocks is not None:
+        config, traffic = harness.shrink(config, traffic, n_blocks)
     return dict(
-        cell=cell,
-        config=load_json(ROOT / conf["file"]),
-        traffic=load_json(BENCH / "traffic" / f"{cell['traffic']}.json"),
+        cell=cell, config=config, traffic=traffic, harness=harness,
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
         per_layer=[m for m in bench["per_layer"] if applies(m)],
     )
-
-
-def shrink(config: dict, traffic: dict, n_blocks: int):
-    """The same deployment and mix at ``n_blocks`` blocks (CPU rehearsals
-    and tests): every block count and batch scales by one factor."""
-    f = n_blocks / config["n_blocks"]
-    config = json.loads(json.dumps(config))
-    traffic = dict(traffic)
-    config["n_blocks"] = n_blocks
-    config["k_hot"] = max(int(config["k_hot"] * f), 1)
-    traffic["batch"] = max(int(traffic["batch"] * f), 1)
-    for r in config["popularity"].get("regions", []):
-        r["start"], r["end"] = int(r["start"] * f), int(r["end"] * f)
-    return config, traffic
 
 
 def check_device(chips: int, require_tpu: bool):
@@ -124,69 +116,24 @@ def device_peaks(kind: str) -> dict:
     return peaks[kind]
 
 
-def load_reader(name: str):
+def _load_module(kind: str, name: str):
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}",
-        BENCH / "metrics" / f"{name}.py")
+        f"bench_{kind}_{name.replace('.', '_')}", BENCH / kind / f"{name}.py")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-class _Scenario:
-    """The deployment as ``EpochRuntime.for_scenario`` reads it."""
-
-    def __init__(self, config: dict, traffic: dict, pool):
-        from repro.core.costmodel import MemSystem, TierSpec
-        ms = config["memory_system"]
-        self.n_blocks = int(config["n_blocks"])
-        self.k_hot = int(config["k_hot"])
-        self.system = MemSystem(fast=TierSpec(**ms["fast"]),
-                                slow=TierSpec(**ms["slow"]), mlp=ms["mlp"])
-        self.bytes_per_access = float(config["bytes_per_access"])
-        self.block_bytes = float(config["block_bytes"])
-        self.pebs_period = int(config["pebs_period"])
-        self.nb_scan_rate = max(self.n_blocks // (
-            int(traffic["batches_per_epoch"])
-            * int(config["nb_scan_passes_per_epoch"])), 1)
-        self._config, self._pool = config, pool
-
-    def hint_layout(self):
-        from repro.hints import HintLayout
-        return HintLayout(
-            self.n_blocks, rank_to_page=self._pool.rank_to_page,
-            alpha=float(self._config["popularity"].get("alpha", 0.0)),
-            rows_per_page=int(self._config.get("rows_per_page", 1)))
+def load_reader(name: str):
+    """The ``read(trace)`` of per-layer metric ``name``."""
+    return _load_module("metrics", name).read
 
 
-def build_runtime(scn: _Scenario, config: dict, traffic: dict):
-    from repro.core.runtime import EpochRuntime
-    from repro.hints import (HintPipeline, LookaheadWindow,
-                             PhaseChangeDetector, StaticTableHints)
-    pipeline = None
-    if traffic.get("hints"):
-        h, t = config["hints"], traffic["hints"]
-        n = scn.n_blocks
-        det = h["detector"]
-        pipeline = HintPipeline(
-            n,
-            static=StaticTableHints(
-                scn.hint_layout(),
-                clip_rank=max(n // int(h["static_clip_divisor"]), 1)),
-            lookahead=LookaheadWindow(n, depth=int(t["depth"]),
-                                      decay=float(h["lookahead_decay"])),
-            detector=(PhaseChangeDetector(n, alpha=det["alpha"],
-                                          threshold=det["threshold"],
-                                          penalty=det["penalty"])
-                      if t.get("detector", True) else None))
-    rt = config["runtime"]
-    return EpochRuntime.for_scenario(
-        scn, policies=tuple(config["lanes"]), hints=pipeline,
-        prefetch_overlap=float(rt["prefetch_overlap"]),
-        sync_every=int(traffic["sync_every"]),
-        ewma_alpha=float(rt["ewma_alpha"]),
-        hint_weight=float(rt["hint_weight"]),
-        hmu_log_capacity=int(rt["hmu_log_capacity"]))
+def load_harness(name: str):
+    """Harness module ``name``: ``make_pool``, ``build_runtime``,
+    ``program_rows``, ``reference`` and ``shrink`` (see
+    ``bench/harness/single.py``)."""
+    return _load_module("harness", name)
 
 
 class _Compiles:
@@ -298,37 +245,59 @@ def serve(rt, pool, traffic: dict, seconds: float, first: int,
     return i - first, lat, t1 - t0
 
 
-def compare(config: dict, program: Dict[str, list], placement: np.ndarray,
-            ref: Dict[str, list], ref_placement: np.ndarray, first: int):
-    """Exact comparison: mismatching record fields, the window epochs they
-    fall in, and mismatching final slot entries."""
-    bad_fields = 0
-    bad_epochs = set()
-    for lane in config["lanes"]:
-        got, want = program[lane], ref[lane]
-        if len(got) != len(want):
-            bad_fields += abs(len(got) - len(want)) * len(RECORD_FIELDS)
-            bad_epochs.update(range(min(len(got), len(want)),
-                                    max(len(got), len(want))))
-        for a, b in zip(got, want):
-            diff = sum(a[f] != b[f] for f in RECORD_FIELDS)
-            if diff:
-                bad_fields += diff
-                bad_epochs.add(b["epoch"])
-    bad_slots = int(np.sum(placement != ref_placement))
+def _count_mismatches(got: list, want: list, bad_epochs: set) -> int:
+    """Mismatching fields between two streams of rows, row by row; a row
+    on one side only counts each of its fields.  Adds the epochs of the
+    rows at fault to ``bad_epochs``."""
+    bad = 0
+    for a, b in itertools.zip_longest(got, want):
+        if a is None or b is None:
+            row = b if a is None else a
+            diff = len(row)
+        else:
+            diff = sum(f not in a or f not in b
+                       or not np.array_equal(a[f], b[f])
+                       for f in a.keys() | b.keys())
+            row = b
+        if diff:
+            bad += diff
+            bad_epochs.add(row["epoch"])
+    return bad
+
+
+def compare(program: tuple, ref: tuple, first: int):
+    """Exact comparison of the program's ``(rows, placements)`` with the
+    reference's: mismatching row fields over every stream, the window
+    epochs (from ``first`` on) that hold them, and mismatching placement
+    entries over every placement array."""
+    (rows, places), (ref_rows, ref_places) = program, ref
+    bad_epochs: set = set()
+    bad_fields = sum(
+        _count_mismatches(rows.get(name, []), ref_rows.get(name, []),
+                          bad_epochs)
+        for name in sorted(rows.keys() | ref_rows.keys()))
+    bad_slots = 0
+    for name in sorted(places.keys() | ref_places.keys()):
+        a, b = places.get(name), ref_places.get(name)
+        if a is None or b is None or np.shape(a) != np.shape(b):
+            bad_slots += max(np.size(x) for x in (a, b) if x is not None)
+        else:
+            bad_slots += int(np.sum(np.asarray(a) != np.asarray(b)))
     failed = len([e for e in bad_epochs if e >= first])
     return bad_fields, bad_slots, failed
 
 
 def run(spec: dict, seed: int, seconds: float, trace: bool, *,
-        require_tpu: bool = True, reference=run_reference,
+        require_tpu: bool = True, reference=None,
         trace_dir: Path = TRACE_DIR) -> dict:
-    """One run of a cell (see the module doc); returns the result object."""
+    """One run of a cell (see the module doc); returns the result object.
+    ``reference`` stands in for the harness module's (tests)."""
     import jax
     from repro.compile_cache import use_compile_cache
     from repro.obs import trace as obs_trace
 
     cell, config, traffic = spec["cell"], spec["config"], spec["traffic"]
+    harness = spec["harness"]
     devices = check_device(int(cell["chips"]), require_tpu)
     kind = devices[0].device_kind
     if require_tpu:
@@ -339,10 +308,9 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
     compiles = _Compiles()
 
     t_dev = time.perf_counter() - T_START
-    pool = make_pool(config, traffic, seed)
+    pool = harness.make_pool(config, traffic, seed)
     t_pool = time.perf_counter() - T_START
-    scn = _Scenario(config, traffic, pool)
-    rt = build_runtime(scn, config, traffic)
+    rt = harness.build_runtime(config, traffic, pool)
     t_built = time.perf_counter() - T_START
     log(f"kernels {json.dumps(rt.kernels)}")
     warm = int(traffic["warmup_epochs"])
@@ -357,7 +325,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
         f"warm-up done {setup_s}")
 
     if trace:
-        if rt.hints is not None:
+        if getattr(rt, "hints", None) is not None:
             _annotate(rt.hints, "epoch_ranks", "hint_ranks")
             _annotate(rt, "set_hint_ranks", "hint_set")
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -385,22 +353,17 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
         f"{compiles_setup} ({hits_setup} from the persistent cache), "
         f"window {compiles_window}")
 
-    program = {name: [r.to_dict() for r in recs]
-               for name, recs in rt.records.items()}
-    lanes = rt.lanes
-    placement = np.stack([lanes[name].slot_to_block
-                          for name in config["lanes"]])
+    program = harness.program_rows(rt, config)
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
-    del rt, lanes, scn
+    del rt
     gc.collect()
 
     t_ref = time.perf_counter()
-    ref, ref_placement = reference(config, traffic, pool, n_epochs)
+    ref = (reference or harness.reference)(config, traffic, pool, n_epochs)
     log(f"reference replayed {n_epochs} epochs in "
         f"{time.perf_counter() - t_ref} s")
-    bad_fields, bad_slots, failed = compare(
-        config, program, placement, ref, ref_placement, warm)
+    bad_fields, bad_slots, failed = compare(program, ref, warm)
     checks = {
         "record_mismatches": {"value": bad_fields, "limit": 0},
         "placement_mismatches": {"value": bad_slots, "limit": 0},
@@ -451,11 +414,9 @@ def main(argv=None) -> int:
                     help="CPU run at a reduced size; prints no result line")
     args = ap.parse_args(argv)
 
-    spec = load_cell(args.workload)
     sys.path.insert(0, str(ROOT / "src"))
-    if args.rehearse:
-        spec["config"], spec["traffic"] = shrink(
-            spec["config"], spec["traffic"], REHEARSAL_BLOCKS)
+    spec = load_cell(args.workload,
+                     REHEARSAL_BLOCKS if args.rehearse else None)
     try:
         result = run(spec, args.seed, args.seconds, bool(args.trace),
                      require_tpu=not args.rehearse)

@@ -4,8 +4,8 @@ Each cell runs through the harness (device check skipped) at 20,000
 blocks; the sound run must compare equal to the plain reference, and every
 fault a one-chip cell can have must come out as not correct:
 
-* the control -- the reference computed with bfloat16 scores, put in the
-  program's place;
+* the control -- the harness module's reference computed with bfloat16
+  scores, put in the program's place;
 * an epoch step that returns its state unchanged;
 * half of every epoch's ids left out of the observe;
 * one record altered where the runtime assembles it.
@@ -16,7 +16,6 @@ import functools
 
 import pytest
 
-import reference
 import run_cell
 
 CELLS = ("dlrm_paper.hinted", "mmap_paper.scan")
@@ -25,10 +24,7 @@ SEED = 2_718_281_828_459      # larger than 32 bits hold
 
 @pytest.fixture(scope="module", params=CELLS)
 def spec(request):
-    s = run_cell.load_cell(request.param)
-    s["config"], s["traffic"] = run_cell.shrink(
-        s["config"], s["traffic"], run_cell.REHEARSAL_BLOCKS)
-    return s
+    return run_cell.load_cell(request.param, run_cell.REHEARSAL_BLOCKS)
 
 
 def _run(spec, **kw):
@@ -44,7 +40,7 @@ def test_sound_run_is_correct(spec):
 
 
 def test_control_is_not_correct(spec):
-    r = _run(spec, reference=functools.partial(reference.run_reference,
+    r = _run(spec, reference=functools.partial(spec["harness"].reference,
                                                control=True))
     assert r["correct"] is False
     assert r["checks"]["record_mismatches"]["value"] > 0
