@@ -96,8 +96,8 @@ select), so a noisy tenant cannot crowd a quiet one out of any lane's
 candidate list — and because ``apply_plan`` never evicts a still-wanted
 resident while ``sum(caps) <= k_hot``, a tenant's capped want is *admitted*
 unconditionally: quotas are isolation guarantees.  Per-tenant accounting
-(tenant-segment reductions over the per-block ``tenant_id`` state leaf plus
-each tenant's own top-``hot_k[t]`` hot set) rides in the same single
+(sums over each tenant's static id range plus each tenant's own
+top-``hot_k[t]`` hot set) rides in the same single
 device->host sync as the scalar record fields, one (L, T) row set per
 epoch in ``EpochRuntime.tenant_records``.
 """
@@ -122,6 +122,7 @@ from ..faults.model import (CARRY_BASE, COLLECTORS, LANE_COLLECTOR,
 from ..kernels.dispatch import PallasBackend, resolve_backend
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
+from ..obs.trace import named_scope
 from .costmodel import CXL_SYSTEM, MemSystem, split_accesses_by_tier
 from .placement import Placement, apply_plan, demote_idle
 
@@ -344,11 +345,6 @@ class Tenancy(NamedTuple):
     def sizes(self) -> Tuple[int, ...]:
         return tuple(b - a for a, b in zip(self.offsets, self.offsets[1:]))
 
-    def block_tenants(self) -> np.ndarray:
-        """Per-block tenant ids, (n_blocks,) int32 — the fused state leaf."""
-        return np.repeat(np.arange(self.n_tenants, dtype=np.int32),
-                         self.sizes)
-
     def validate(self, n_blocks: int, k_hot: int) -> None:
         offs = self.offsets
         if len(offs) < 2 or offs[0] != 0 or offs[-1] != n_blocks or any(
@@ -396,8 +392,6 @@ class _FusedState:
     prefetch_rank: jax.Array     # (n_blocks,) f32 lookahead priorities
     prev_hmu: jax.Array          # (n_blocks,) i32 epoch-delta baselines
     prev_pebs: jax.Array
-    tenant_id: jax.Array         # (n_blocks,) i32 tenant of each block
-                                 # (all-zero without a Tenancy)
     out_buf: Dict[str, jax.Array]
                                  # stacked (sync_every,)-leading record
                                  # fields: scalars (K,), per-lane (K, L),
@@ -662,10 +656,11 @@ def _epoch_step(state: _FusedState, epoch_accesses: jax.Array,
     ten = cfg.tenancy
     quotas = ten is not None and ten.caps is not None
     if quotas:
-        protected = selectk.segment_top_k_mask(key_rows, ten.offsets,
-                                               ten.caps, backend=cfg.pallas)
-        key_rows = jnp.where(protected, key_rows,
-                             jnp.iinfo(jnp.int32).min)
+        with named_scope("tenancy.select"):
+            protected = selectk.segment_top_k_mask(
+                key_rows, ten.offsets, ten.caps, backend=cfg.pallas)
+            key_rows = jnp.where(protected, key_rows,
+                                 jnp.iinfo(jnp.int32).min)
 
     # -- one O(n) selection per unique signal, fanned out to lanes
     vals_u, ids_u, sel_u = selectk.select_top_k(key_rows, k, return_mask=True,
@@ -676,9 +671,13 @@ def _epoch_step(state: _FusedState, epoch_accesses: jax.Array,
     #    (pre-migration).  The hot set is workload truth: with faults or
     #    staleness the hmu selection row no longer ranks the truth, so it
     #    gets its own exact top-K; otherwise the oracle row doubles as it.
-    hot = (selectk.top_k_mask(d_true, k, backend=cfg.pallas)
-           if quotas or faulty or state.stale is not None
-           else sel_u[hmu_row])                    # epoch's true top-K set
+    if quotas:
+        with named_scope("tenancy.hot_set"):
+            hot = selectk.top_k_mask(d_true, k, backend=cfg.pallas)
+    elif faulty or state.stale is not None:
+        hot = selectk.top_k_mask(d_true, k, backend=cfg.pallas)
+    else:
+        hot = sel_u[hmu_row]                       # epoch's true top-K set
     fast0 = state.placement.fast_mask              # (L, n)
     n_fast = jnp.sum(jnp.where(fast0, d_true, 0), axis=-1)
     n_slow = jnp.sum(d_true) - n_fast
@@ -725,25 +724,25 @@ def _epoch_step(state: _FusedState, epoch_accesses: jax.Array,
         # hot_k[t] of its id range — the coverage target it would have solo).
         # All outputs are (L, T) scalars-per-tenant; nothing (n,)-sized
         # leaves the device.
-        tsum = partial(_per_tenant_sum, tenant_id=state.tenant_id,
-                       n_tenants=ten.n_tenants)
-        hot_parts = [
-            selectk.top_k_mask(
-                jax.lax.slice_in_dim(d_true, ten.offsets[t],
-                                     ten.offsets[t + 1]),
-                ten.hot_k[t], backend=cfg.pallas)
-            for t in range(ten.n_tenants)
-        ]
-        t_hot = jnp.concatenate(hot_parts)
+        tsum = partial(_per_tenant_sum, offsets=ten.offsets)
+        with named_scope("tenancy.hot_set"):
+            t_hot = jnp.concatenate([
+                selectk.top_k_mask(
+                    jax.lax.slice_in_dim(d_true, ten.offsets[t],
+                                         ten.offsets[t + 1]),
+                    ten.hot_k[t], backend=cfg.pallas)
+                for t in range(ten.n_tenants)
+            ])
         fast1 = pl.fast_mask
-        out["tenant"] = {
-            "n_fast": tsum(jnp.where(fast0, d_true, 0)),
-            "n_slow": tsum(jnp.where(fast0, 0, d_true)),
-            "inter": tsum(fast0 & t_hot),
-            "resident": tsum(fast0),
-            "promoted": tsum(fast1 & ~fast0),
-            "demoted": tsum(fast0 & ~fast1),
-        }
+        with named_scope("tenancy.account"):
+            out["tenant"] = {
+                "n_fast": tsum(jnp.where(fast0, d_true, 0)),
+                "n_slow": tsum(jnp.where(fast0, 0, d_true)),
+                "inter": tsum(fast0 & t_hot),
+                "resident": tsum(fast0),
+                "promoted": tsum(fast1 & ~fast0),
+                "demoted": tsum(fast0 & ~fast1),
+            }
     # -- append this epoch's record row to the device-side accumulator
     #    (same pytree structure as out; dtypes fixed by _out_buf_init)
     out_buf = jax.tree_util.tree_map(
@@ -765,14 +764,14 @@ def _epoch_step(state: _FusedState, epoch_accesses: jax.Array,
     return dataclasses.replace(state, **updates)
 
 
-def _per_tenant_sum(x: jax.Array, tenant_id: jax.Array,
-                    n_tenants: int) -> jax.Array:
-    """(..., n_blocks) -> (..., T): segment reduction over the tenant leaf."""
-    flat = x.astype(jnp.int32).reshape((-1, x.shape[-1]))
-    out = jax.vmap(lambda row: jax.ops.segment_sum(
-        row, tenant_id, num_segments=n_tenants,
-        indices_are_sorted=True))(flat)
-    return out.reshape(x.shape[:-1] + (n_tenants,))
+def _per_tenant_sum(x: jax.Array, offsets: Tuple[int, ...]) -> jax.Array:
+    """(..., n_blocks) -> (..., T) int32: one sum per tenant over its static
+    contiguous id range ``offsets[t]:offsets[t+1]`` -- reductions, where a
+    segment sum over a per-block tenant id would scatter every element onto
+    T addresses."""
+    x = x.astype(jnp.int32)
+    return jnp.stack([jnp.sum(x[..., a:b], axis=-1)
+                      for a, b in zip(offsets, offsets[1:])], axis=-1)
 
 
 class EpochRuntime:
@@ -901,9 +900,6 @@ class EpochRuntime:
         self.tenant_records: List[Dict[str, np.ndarray]] = []
         if tenancy is not None:
             tenancy.validate(self.n_blocks, self.k_hot)
-            self._tenant_id_host = tenancy.block_tenants()
-        else:
-            self._tenant_id_host = np.zeros((self.n_blocks,), np.int32)
         self.faults = faults
         self.hardening = hardening
         # Optional repro.export client (duck-typed: export_epoch_record).
@@ -964,7 +960,6 @@ class EpochRuntime:
                 hint_rank=jnp.asarray(self.hint_rank),
                 prefetch_rank=jnp.asarray(self.prefetch_rank),
                 prev_hmu=zeros_n(), prev_pebs=zeros_n(),
-                tenant_id=jnp.asarray(self._tenant_id_host),
                 out_buf=_out_buf_init(self.sync_every, L, self.tenancy,
                                       self.hardening),
                 **extra,

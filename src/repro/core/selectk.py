@@ -326,7 +326,10 @@ def segment_top_k_mask(key: jax.Array, bounds, caps, *,
             use_pallas=True, interpret=backend.interpret)   # (B, S) uint32
 
     def widen(per_seg):             # (B, S) -> (B, n), constant per segment
-        return jnp.repeat(per_seg, lens, axis=-1, total_repeat_length=n)
+        # one broadcast per static segment: no index arrays, no gather
+        return jnp.concatenate(
+            [jnp.broadcast_to(per_seg[:, s:s + 1], (per_seg.shape[0], int(l)))
+             for s, l in enumerate(lens)], axis=-1)
 
     t_elem = widen(t)
     gt = u > t_elem

@@ -5,7 +5,8 @@ refuses block shapes that break the (8, 128) tiling rule, primitives with
 no Mosaic lowering, and programs that do not fit the chip.  These tests
 compile — nothing runs — every kernel left on the default TPU path at the
 sizes the runtime uses, plus the fused epoch programs at paper-scale DLRM
-(5,000,000 pages), for one chip of a ``v5e:2x2`` topology.
+(5,000,000 pages) and at the two-tenant fleet cell (7,621,440 pages under
+weighted-fair quotas), for one chip of a ``v5e:2x2`` topology.
 
 The topology is described inside a module-scoped fixture, never while the
 module is imported: only one process at a time may load the TPU library,
@@ -84,15 +85,32 @@ def test_hist_select_compiles(one_chip, no_cache, n, n_segments):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _paper_runtime_shapes(one_chip):
-    """The paper-scale runtime's fused state, as shapes on the described
-    chip: the constructor runs under ``eval_shape``, so nothing of
-    paper size is allocated here."""
+# the two-tenant fleet cell (bench/configs/fleet_paper.json): DLRM's
+# 5,000,000 pages then mmap-bench's 2,621,440, capped at their hot sets
+FLEET_OFFSETS = (0, 5_000_000, 7_621_440)
+FLEET_CAPS = (486_587, 262_144)
+
+
+def test_hist_select_compiles_at_the_fleet_cell(one_chip, no_cache):
+    """The fleet's segment-capped select: B=5 unique key rows over
+    7,621,440 blocks in two tenant segments."""
+    n = FLEET_OFFSETS[-1]
+    f = jax.jit(lambda u, seg: kth_key_u(
+        u, seg, FLEET_CAPS, tile_n=TPU_BACKEND.select_tile_n,
+        use_pallas=True, interpret=False))
+    compiled = f.lower(_spec((5, n), jnp.uint32, one_chip),
+                       _spec((n,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _runtime_shapes(one_chip, make):
+    """``make()``'s runtime and its fused state, as shapes on the
+    described chip: the constructor runs under ``eval_shape``, so nothing
+    of paper size is allocated here."""
     box = {}
 
     def build():
-        box["rt"] = run = rt.EpochRuntime.for_scenario(
-            DLRMScenario(spec=datagen.PAPER), use_pallas=False, sync_every=2)
+        box["rt"] = run = make()
         return run._state
 
     state = jax.eval_shape(build)
@@ -101,14 +119,18 @@ def _paper_runtime_shapes(one_chip):
     return box["rt"], state
 
 
-def test_paper_scale_fused_epoch_compiles_and_fits(one_chip, no_cache):
-    spec = datagen.PAPER
-    run, state = _paper_runtime_shapes(one_chip)
-    assert run.n_blocks == spec.n_pages == 5_000_000
-    batches = _spec((4, spec.lookups_per_batch), jnp.int32, one_chip)
+def _paper_runtime_shapes(one_chip):
+    return _runtime_shapes(one_chip, lambda: rt.EpochRuntime.for_scenario(
+        DLRMScenario(spec=datagen.PAPER), use_pallas=False, sync_every=2))
+
+
+def _compile_epoch_and_check_fit(one_chip, run, state, batch_shape):
+    """observe_all and _epoch_step compiled for the chip on the TPU
+    backend: the select kernel is in the step, and each fits the chip."""
+    batches = _spec(batch_shape, jnp.int32, one_chip)
     observe = tel.observe_all.lower(state.bundle, batches,
                                     pallas=TPU_BACKEND).compile()
-    bound = 4 * spec.lookups_per_batch // state.bundle.pebs.period + 2
+    bound = batch_shape[0] * batch_shape[1] // state.bundle.pebs.period + 2
     s_max = min(run.n_blocks, 1 << (bound - 1).bit_length())
     scalar = _spec((), jnp.int32, one_chip)
     step = rt._epoch_step.lower(
@@ -120,3 +142,23 @@ def test_paper_scale_fused_epoch_compiles_and_fits(one_chip, no_cache):
         need = (m.argument_size_in_bytes + m.output_size_in_bytes
                 - m.alias_size_in_bytes + m.temp_size_in_bytes)
         assert 0 < need < V5E_HBM_BYTES, need
+
+
+def test_paper_scale_fused_epoch_compiles_and_fits(one_chip, no_cache):
+    spec = datagen.PAPER
+    run, state = _paper_runtime_shapes(one_chip)
+    assert run.n_blocks == spec.n_pages == 5_000_000
+    _compile_epoch_and_check_fit(one_chip, run, state,
+                                 (4, spec.lookups_per_batch))
+
+
+def test_fleet_scale_fused_epoch_compiles_and_fits(one_chip, no_cache):
+    """The fleet cell's epoch: weighted-fair quotas over both tenants,
+    four interleaved rows of 4,497,152 accesses."""
+    run, state = _runtime_shapes(one_chip, lambda: rt.EpochRuntime(
+        FLEET_OFFSETS[-1], sum(FLEET_CAPS), pebs_period=401,
+        nb_scan_rate=FLEET_OFFSETS[-1] // 4, use_pallas=False,
+        tenancy=rt.Tenancy(offsets=FLEET_OFFSETS, hot_k=FLEET_CAPS,
+                           caps=FLEET_CAPS)))
+    assert run._cfg.tenancy.caps == FLEET_CAPS
+    _compile_epoch_and_check_fit(one_chip, run, state, (4, 4_497_152))

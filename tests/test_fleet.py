@@ -257,6 +257,24 @@ def test_run_scenario_generic_path_inherits_tenancy():
 
 
 # --------------------------------------------------------- accounting
+@pytest.mark.parametrize("offsets", [(0, 7), (0, 3, 10), (0, 500, 501, 2048)])
+@pytest.mark.parametrize("kind", ["mask", "count"])
+def test_per_tenant_sum_equals_the_segment_sum(offsets, kind):
+    """The static-range sums give what a segment sum over the per-block
+    tenant ids gives, on (lanes, n) masks and counts."""
+    import jax
+    rng = np.random.default_rng(len(offsets))
+    n = offsets[-1]
+    x = (rng.random((6, n)) < 0.3 if kind == "mask"
+         else rng.integers(0, 1 << 20, (6, n), dtype=np.int32))
+    tenant_of = np.repeat(np.arange(len(offsets) - 1), np.diff(offsets))
+    want = jax.vmap(lambda row: jax.ops.segment_sum(
+        row, tenant_of, num_segments=len(offsets) - 1))(x.astype(np.int32))
+    got = rtmod._per_tenant_sum(x, offsets)
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+
+
 def test_per_tenant_accounting_conserves_the_global_record():
     """ISSUE acceptance: tenant numerators sum to the global record — every
     conservable column (n_fast / n_slow / resident / promoted / demoted)
@@ -362,8 +380,8 @@ def test_shared_pool_interference_vs_weighted_fair_isolation():
 @pytest.mark.slow
 def test_sharded_fleet_parity():
     """ISSUE acceptance: the quota-enforcing fleet epoch with all per-block
-    state (tenant_id leaf included) sharded over an 8-device mesh equals the
-    single-device run exactly."""
+    state sharded over an 8-device mesh equals the single-device run
+    exactly."""
     r = subprocess.run([sys.executable, "-c", textwrap.dedent("""
         import dataclasses, json
         import numpy as np

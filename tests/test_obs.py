@@ -15,8 +15,8 @@ watching the runtime must cost the watched system nothing:
   ``pipelining_visible``: structurally True for a ``sync_every=K>1`` span
   pattern, False for K=1.
 * **Named work** — the compiled epoch programs carry the ``selectk.*``,
-  ``placement.free_slots`` and ``telemetry.*`` scopes and the same ops
-  without them; a traced fused epoch records one ``id_upload``,
+  ``placement.free_slots`` and ``telemetry.*`` scopes (a quota fleet's
+  also ``tenancy.*``) and the same ops without them; a traced fused epoch records one ``id_upload``,
   ``record_wait``, ``record_pull`` and ``record_assembly`` (and, hinted,
   ``hints``, ``hints.detector``, ``hints.lookahead``), with span args
   reaching ``jax.profiler.TraceAnnotation``.
@@ -531,10 +531,14 @@ def _scope_names(hlo_text):
 
 
 def _without_metadata(hlo_text):
-    """Compiled HLO with op metadata and the trailing debug tables cut."""
+    """Compiled HLO with op metadata and the debug tables cut: the file,
+    function, location and stack-frame tables printed between the
+    module's header and its computations."""
     import re
-    return re.sub(r", metadata=\{[^}]*\}", "",
-                  hlo_text.split("\nFileNames")[0])
+    lines = [line for line in hlo_text.splitlines()
+             if not re.match(r"(FileNames|FunctionNames|FileLocations|"
+                             r"StackFrames)$|\d+ ", line)]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
 
 
 def _compiled_programs(rt, faults=False):
@@ -621,6 +625,60 @@ class TestNamedScopes:
         (eqn,) = [e for e in jaxpr.jaxpr.eqns
                   if e.primitive.name == "pallas_call"]
         assert eqn.params["name"] == "hist_select"
+
+
+TENANCY_SCOPES = ("tenancy.select", "tenancy.hot_set", "tenancy.account")
+
+
+class TestTenancyScopes:
+    """What a quota fleet adds to the epoch step is named, and a runtime
+    without a Tenancy compiles exactly as without the names."""
+    N, K = 2048, 128
+
+    @pytest.fixture(scope="class")
+    def runtimes(self):
+        from repro.core.runtime import Tenancy
+        fleet = EpochRuntime(
+            self.N, self.K, pebs_period=8, nb_scan_rate=self.N // 4,
+            fused=True, tenancy=Tenancy(offsets=(0, 1536, self.N),
+                                        hot_k=(96, 32), caps=(96, 32)))
+        plain = EpochRuntime(self.N, self.K, pebs_period=8,
+                             nb_scan_rate=self.N // 4, fused=True)
+        return {"fleet": fleet, "plain": plain}
+
+    @pytest.fixture(scope="class")
+    def steps(self, runtimes):
+        return {name: _compiled_programs(rt)[0]
+                for name, rt in runtimes.items()}
+
+    @pytest.fixture(scope="class")
+    def bare_steps(self, runtimes):
+        """The same programs with the runtime's scopes left out."""
+        import contextlib
+        mp = pytest.MonkeyPatch()
+        mp.setattr(rtmod, "named_scope",
+                   lambda name: contextlib.nullcontext())
+        try:
+            return {name: _compiled_programs(rt)[0]
+                    for name, rt in runtimes.items()}
+        finally:
+            mp.undo()
+
+    @pytest.mark.parametrize("scope", TENANCY_SCOPES)
+    def test_fleet_epoch_step_names_the_scope(self, steps, scope):
+        assert scope in _scope_names(steps["fleet"])
+
+    def test_scopes_leave_the_fleet_ops_alone(self, steps, bare_steps):
+        assert not _scope_names(bare_steps["fleet"]) & set(TENANCY_SCOPES)
+        assert (_without_metadata(steps["fleet"])
+                == _without_metadata(bare_steps["fleet"]))
+
+    def test_program_without_tenancy_is_unchanged(self, steps, bare_steps):
+        assert not _scope_names(steps["plain"]) & set(TENANCY_SCOPES)
+        assert _scope_names(steps["plain"]) == _scope_names(
+            bare_steps["plain"])
+        assert (_without_metadata(steps["plain"])
+                == _without_metadata(bare_steps["plain"]))
 
 
 # ------------------------------------------------ compaction algorithm count
